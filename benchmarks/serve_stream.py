@@ -124,6 +124,11 @@ async def _parity(cfg, model, params, dcfg) -> dict:
     }
 
 
+# the load generator needs no device: on the CPU it never contends for
+# the chip this process holds
+_LOADGEN_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+
+
 async def _load(model, params, dcfg, replicas: int,
                 tick_floor_s) -> dict:
     from repro.serving.frontend import build_frontend
@@ -145,7 +150,7 @@ async def _load(model, params, dcfg, replicas: int,
             "--seed", str(SEED), "--window", str(WINDOW_S),
             "--scrape-metrics",       # mid-load /metrics parse+monotone
             stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.PIPE)
+            stderr=asyncio.subprocess.PIPE, env=_LOADGEN_ENV)
         out, err = await proc.communicate()
         if proc.returncode:
             raise RuntimeError(f"loadgen failed: {err.decode()[:500]}")
@@ -187,7 +192,7 @@ async def _slo_load(model, params, dcfg) -> dict:
             "--seed", str(SEED), "--window", str(WINDOW_S),
             "--class-mix", json.dumps(CLASS_MIX),
             stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.PIPE)
+            stderr=asyncio.subprocess.PIPE, env=_LOADGEN_ENV)
         out, err = await proc.communicate()
         if proc.returncode:
             raise RuntimeError(f"loadgen failed: {err.decode()[:500]}")

@@ -73,13 +73,17 @@ def primitive_census(jaxpr) -> Dict[str, int]:
     return census
 
 
+_PRIMITIVE_ALIASES = {"psum_invariant": "psum"}
+
+
 def collective_axes(jaxpr) -> Dict[str, Set[str]]:
     """primitive name -> set of *named* axes it reduces/permutes over.
-    Versioned primitive names (``psum2`` under shard_map) are normalized
-    to their base name."""
+    ``psum`` of a value that is invariant over its axes lowers to
+    ``psum_invariant`` inside a checked shard_map; it is reported as
+    ``psum``."""
     out: Dict[str, Set[str]] = {}
     for eqn in iter_eqns(jaxpr):
-        name = eqn.primitive.name.rstrip("0123456789")
+        name = _PRIMITIVE_ALIASES.get(eqn.primitive.name, eqn.primitive.name)
         if name not in registry.COLLECTIVE_PRIMITIVES:
             continue
         axes: Set[str] = set()

@@ -551,7 +551,6 @@ def get_spmd_tick_fn(model, dcfg: DiffusionConfig, mask_id: int, mesh,
     full-row blocks and the combine's lowest-index tie-break matches the
     fused scan's first-chunk-wins rule (pinned by tests/test_spmd.py).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     for ax in ("data", "model"):
@@ -592,7 +591,7 @@ def get_spmd_tick_fn(model, dcfg: DiffusionConfig, mask_id: int, mesh,
         pspec["lm_head"] = P(None, "model")
         cspec = jax.tree.map(lambda _: P(None, "data"), cache)
         row = P("data")
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P("data", None), P("data", None), row, row,
                       P(), cspec),
@@ -764,7 +763,6 @@ def get_megatick_fn(model, dcfg: DiffusionConfig, mask_id: int, k_max: int,
         return (jax.jit(megatick, donate_argnums=(1, 7)) if jit_steps
                 else megatick)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_model = mesh.shape["model"]
@@ -789,12 +787,12 @@ def get_megatick_fn(model, dcfg: DiffusionConfig, mask_id: int, k_max: int,
                     "masks_left", "k", "conf", "active", "released",
                     "early"):
             bspec[key] = P(None, "data")
-        f = shard_map(
+        f = jax.shard_map(
             functools.partial(body, axis_name="model"), mesh=mesh,
             in_specs=(pspec, P("data", None), P("data", None), sspec,
                       P(), P(), P(), cspec),
             out_specs=(P("data", None), cspec, P(), sspec, bspec, P()),
-            check_rep=False)
+            check_vma=False)
         return f(params, x, kv_valid, state, rng, k_req, stop_on_release,
                  cache)
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -78,6 +79,22 @@ def _quant_grid(x: jax.Array, grid: np.ndarray) -> jax.Array:
     return jnp.sign(x) * jnp.asarray(grid, x.dtype)[idx]
 
 
+def _pow2(e: jax.Array) -> jax.Array:
+    """Exact f32 2**e for integer e in [-127, 127], built from the exponent
+    bits: exp2 is not exact on every backend (XLA's CPU exp2(-17) is off
+    in the last bits), and an MX scale has to be a power of two."""
+    e = jnp.asarray(e, jnp.int32)
+    normal = jax.lax.bitcast_convert_type(
+        (jnp.maximum(e, -126) + 127) << 23, jnp.float32)
+    return jnp.where(e >= -126, normal, 2.0 ** -127)
+
+
+def _exponent(x: jax.Array) -> jax.Array:
+    """Unbiased exponent field of f32 ``x`` (-127 for zero)."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return ((bits >> 23) & 0xFF) - 127
+
+
 def _quant_element(x: jax.Array, fmt: MXFormat) -> jax.Array:
     """Quantize scaled elements x (already divided by the shared scale)."""
     if fmt.is_int:
@@ -86,11 +103,17 @@ def _quant_element(x: jax.Array, fmt: MXFormat) -> jax.Array:
         q = jnp.clip(_round_half_away(x * (2 ** fmt.frac_bits)), lo, hi)
         return q * (2.0 ** -fmt.frac_bits)
     if fmt is MXFP8:
-        # OCP MX requires *saturating* conversion; ml_dtypes e4m3fn
-        # conversion NaNs on overflow (scaled block max lies in [256, 512),
-        # above e4m3's 448), so clip explicitly.
-        return jnp.clip(x, -448.0, 448.0).astype(
-            jnp.float8_e4m3fn).astype(x.dtype)
+        # OCP MX requires *saturating* conversion, so clip to e4m3's 448
+        # first.  The round-to-nearest-even onto the e4m3 grid (3 mantissa
+        # bits, subnormal step 2^-9) is spelled out rather than cast:
+        # XLA's f32 -> float8_e4m3fn convert on TPU v5e does not round like
+        # ml_dtypes, while this form is exact on every backend and in
+        # Pallas kernels.
+        x = jnp.clip(x, -448.0, 448.0)
+        step = jnp.maximum(_exponent(x), -6) - 3
+        return jax.lax.round(x * _pow2(-step),
+                             jax.lax.RoundingMethod.TO_NEAREST_EVEN) * \
+            _pow2(step)
     if fmt is MXFP6:
         return _quant_grid(x, _E3M2_GRID)
     if fmt is MXFP4:
@@ -98,16 +121,29 @@ def _quant_element(x: jax.Array, fmt: MXFormat) -> jax.Array:
     raise ValueError(f"unknown element format {fmt}")
 
 
-def _shared_scale(amax: jax.Array, fmt: MXFormat) -> jax.Array:
-    """E8M0 power-of-two block scale: smallest 2^e with amax/2^e <= grid_max.
+def _shared_exp(amax: jax.Array, fmt: MXFormat) -> jax.Array:
+    """E8M0 exponent of the block scale: the smallest e with
+    amax / 2^e <= grid_max, clipped to [-127, 127] (0 for an all-zero
+    block).
 
     (ceil variant: the naive floor(log2 amax) - emax mapping can leave the
     block max up to 2x above the element grid -> saturation; ceil keeps
-    every element representable and makes fake-quant idempotent.)"""
-    safe = jnp.where(amax > 0, amax, 1.0)
-    e = jnp.ceil(jnp.log2(safe / fmt.grid_max))
-    e = jnp.clip(e, -127.0, 127.0)
-    return jnp.where(amax > 0, jnp.exp2(e), 1.0)
+    every element representable and makes fake-quant idempotent.)  Read
+    off the exponent and mantissa bits of amax and grid_max, so no log2
+    or division rounds it."""
+    g_mant, g_exp = math.frexp(fmt.grid_max)          # g = g_mant * 2^g_exp
+    g_bits = int((2.0 * g_mant - 1.0) * (1 << 23))    # mantissa of 2*g_mant
+    bits = jax.lax.bitcast_convert_type(amax.astype(jnp.float32), jnp.int32)
+    e = _exponent(amax) - (g_exp - 1) + \
+        ((bits & 0x7FFFFF) > g_bits).astype(jnp.int32)
+    return jnp.where(amax > 0, jnp.clip(e, -127, 127), 0)
+
+
+def _quant_blocks(xb: jax.Array, amax: jax.Array, fmt: MXFormat
+                  ) -> jax.Array:
+    """Fake-quantize ``xb`` against its (broadcastable) block maxima."""
+    e = _shared_exp(amax, fmt)
+    return _quant_element(xb * _pow2(-e), fmt) * _pow2(e)
 
 
 def _blockize(x: jax.Array, block: int) -> Tuple[jax.Array, int]:
@@ -125,9 +161,7 @@ def _fake_quant_impl(x: jax.Array, fmt_name: str, block: int) -> jax.Array:
     orig_dtype = x.dtype
     n = x.shape[-1]
     xb, _ = _blockize(x.astype(jnp.float32), block)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    scale = _shared_scale(amax, fmt)
-    q = _quant_element(xb / scale, fmt) * scale
+    q = _quant_blocks(xb, jnp.max(jnp.abs(xb), axis=-1, keepdims=True), fmt)
     q = q.reshape(*x.shape[:-1], -1)[..., :n]
     return q.astype(orig_dtype)
 
@@ -152,10 +186,8 @@ def mx_quantize(x: jax.Array, fmt: MXFormat | str, block: int = MX_BLOCK
     """Return (element codes as float, shared scales).  Last-axis blocks."""
     fmt = FORMATS[fmt] if isinstance(fmt, str) else fmt
     xb, _ = _blockize(x.astype(jnp.float32), block)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    scale = _shared_scale(amax, fmt)
-    codes = _quant_element(xb / scale, fmt)
-    return codes, scale
+    e = _shared_exp(jnp.max(jnp.abs(xb), axis=-1, keepdims=True), fmt)
+    return _quant_element(xb * _pow2(-e), fmt), _pow2(e)
 
 
 def mx_dequantize(codes: jax.Array, scale: jax.Array, n: int | None = None,

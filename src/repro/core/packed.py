@@ -34,12 +34,10 @@ class PackedMX(NamedTuple):
 def _block_codes(x: jax.Array, fmt: mx.MXFormat, block: int):
     """-> (int codes (..., nb, block), biased exponents (..., nb))."""
     xb, _ = mx._blockize(x.astype(jnp.float32), block)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    scale = mx._shared_scale(amax, fmt)
-    q = mx._quant_element(xb / scale, fmt)          # grid values
+    e = mx._shared_exp(jnp.max(jnp.abs(xb), axis=-1, keepdims=True), fmt)
+    q = mx._quant_element(xb * mx._pow2(-e), fmt)     # grid values
     codes = jnp.round(q * (2.0 ** fmt.frac_bits)).astype(jnp.int8)
-    exp = jnp.round(jnp.log2(scale[..., 0])).astype(jnp.int32) + 127
-    return codes, exp.astype(jnp.uint8)
+    return codes, (e[..., 0] + 127).astype(jnp.uint8)
 
 
 def pack(x: jax.Array, fmt_name: str = "mxint4", block: int = 32

@@ -218,7 +218,9 @@ def counter_gumbel(seed: jax.Array, rows: jax.Array, cols: jax.Array
     h = _mix32(rows.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)
                ^ seed.astype(jnp.uint32))
     h = _mix32(h ^ cols.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
-    u = ((h >> jnp.uint32(8)).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    # the top 24 bits fit int32 exactly; Mosaic has no uint32 -> f32 cast
+    u = ((h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) + 0.5) \
+        * (1.0 / (1 << 24))
     return -jnp.log(-jnp.log(u))
 
 
@@ -336,6 +338,12 @@ def fused_head_local_partials(hidden: jax.Array, w_shard: jax.Array,
 
     init = (jnp.full((R,), NEG_INF), jnp.zeros((R,), jnp.int32),
             jnp.zeros((R,), jnp.float32))
+    # inside shard_map the carry turns varying over every manual axis the
+    # operands vary over; the scan needs its init typed the same way
+    vma = tuple(sorted(jax.typeof(hidden).vma | jax.typeof(w_shard).vma
+                       | jax.typeof(col_offset).vma))
+    if vma:
+        init = jax.lax.pcast(init, vma, to="varying")
     with trace_lib.suppress():
         (m, idx, s), _ = jax.lax.scan(body, init,
                                       jnp.arange(n_chunks, dtype=jnp.int32))
@@ -487,7 +495,7 @@ def sharded_fused_sampling_step_full(hidden: jax.Array, w_shard: jax.Array,
 # Position-level top-k transfer mask (V_TOPK_MASK) + commit (V_SELECT_INT)
 # ---------------------------------------------------------------------------
 
-NEG_INF = jnp.float32(-1e30)
+NEG_INF = -1e30  # python float: importing builds no device array
 
 
 def topk_transfer_mask(conf: jax.Array, mask_idx: jax.Array,
@@ -506,7 +514,11 @@ def topk_transfer_mask(conf: jax.Array, mask_idx: jax.Array,
         trace_lib.emit("S_MAP_V_FP", (B * L,), stage="commit")
         trace_lib.emit("V_TOPK_MASK_PER_ELT", (B * L,), stage="commit")
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        # a Pallas kernel cannot be traced inside a vma-checked shard_map
+        # (its body mixes varying refs with constants): varying inputs
+        # take the jnp path
+        use_kernel = (jax.default_backend() == "tpu"
+                      and not jax.typeof(conf).vma)
     if use_kernel:
         from repro.kernels import ops                  # lazy: avoid cycle
         return ops.transfer_mask(conf.astype(jnp.float32), mask_idx, k)
@@ -574,7 +586,8 @@ def fused_sampling_step_full(hidden: jax.Array, w_head: jax.Array,
     """``sampling_step_full`` fed by active-block *hidden states* instead of
     logits: hidden (B, L, d) + w_head (d, V) stream through the fused
     head + Stable-Max reduction (Pallas kernel on TPU, lax.scan oracle
-    elsewhere) so the (B, L, V) logits never exist in HBM.  Greedy tokens
+    elsewhere) so the (B, L, V) logits never exist in HBM.  On the kernel
+    path a format outside ``SUPPORTED_FMTS`` raises.  Greedy tokens
     are bit-identical to the unfused path (pinned by
     tests/test_fused_head.py); temperature > 0 draws from the counter-based
     Gumbel stream instead of jax.random.gumbel."""
@@ -585,10 +598,6 @@ def fused_sampling_step_full(hidden: jax.Array, w_head: jax.Array,
     temp = cfg.temperature if rng is not None else 0.0
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
-    if use_kernel:
-        from repro.kernels import fused_head_sampling as _fh
-        if cfg.fmt not in _fh.SUPPORTED_FMTS:
-            use_kernel = False   # oracle handles every mx.FORMATS entry
     if use_kernel:
         from repro.kernels import ops                  # lazy: avoid cycle
         seed = gumbel_seed(rng) if temp > 0.0 else jnp.uint32(0)

@@ -1,84 +1,37 @@
-"""Deployment hygiene shared by the serving CLI and engine warmup.
+"""Persistent XLA compilation cache for the serving entry points.
 
-Two pieces, both the kind of thing production JAX serving stacks (the
-maxtext decode microbenchmarks, the SNIPPETS run.sh exemplars) set up
-before the first compile and this repo previously left to the operator:
+Megatick executables are while_loops over the full tick body, so their
+compiles are the most expensive in the repo; with the cache on, every
+process after the first starts serving at full tick rate.
 
-* a **persistent compilation cache** (``jax.experimental.
-  compilation_cache``): megatick executables are while_loops over the
-  full tick body, so their compiles are the most expensive in the repo —
-  caching them under ``~/.cache/repro-xla`` (or ``--compilation-cache-dir``
-  / ``$JAX_COMPILATION_CACHE_DIR``) makes every process after the first
-  start serving at full tick rate with no jit wall time;
-* **tuned default XLA flags**, appended to ``$XLA_FLAGS`` only when the
-  operator has not already set them (and before the backend initializes —
-  call :func:`setup_xla_flags` ahead of the first ``jax.devices()`` /
-  computation).  Only global DebugOptions flags are used so the same set
-  parses on every backend.
-
-Everything is best-effort: failures log and degrade to the uncached,
-unflagged behavior instead of taking serving down.
+The cache lives where ``$JAX_COMPILATION_CACHE_DIR`` says when it is set,
+and otherwise in ``.xla_cache/`` at the root of the checkout (listed in
+``.gitignore``): one fixed directory, so a later process finds what an
+earlier one wrote.  Only entry points (``launch/serve.py``,
+``chip_smoke.py``) call :func:`ensure_compilation_cache`, before their
+first compile; library code and tests never arm it.
 """
 from __future__ import annotations
 
 import os
-from typing import Iterable, Optional
 
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro-xla")
-
-# Global DebugOptions flags (parse on CPU/GPU/TPU jaxlib builds alike).
-# The latency-hiding scheduler overlaps the megatick's per-iteration
-# collectives/HBM traffic with compute on accelerator backends; it is a
-# no-op for the CPU test/CI runs.
-TUNED_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-)
-
-_cache_dir_set: Optional[str] = None
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 
-def setup_xla_flags(extra: Iterable[str] = ()) -> str:
-    """Append tuned default flags to ``$XLA_FLAGS`` (respecting any value
-    the operator already set — a flag whose name is already present is
-    never overridden).  Must run before the XLA backend initializes to
-    take effect; returns the resulting flag string."""
-    current = os.environ.get("XLA_FLAGS", "")
-    add = [f for f in (*TUNED_XLA_FLAGS, *extra)
-           if f.split("=", 1)[0] not in current]
-    if add:
-        current = (current + " " + " ".join(add)).strip()
-        os.environ["XLA_FLAGS"] = current
-    return current
+def ensure_compilation_cache() -> str:
+    """Point jax's persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` or, when unset, the checkout's
+    ``.xla_cache/``.  Call before the first compile; returns the dir."""
+    import jax
 
-
-def ensure_compilation_cache(cache_dir: Optional[str] = None
-                             ) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (default:
-    ``$JAX_COMPILATION_CACHE_DIR`` or ``~/.cache/repro-xla``).  Idempotent
-    and best-effort; returns the active cache dir, or None when the
-    runtime has no usable cache support."""
-    global _cache_dir_set
-    if _cache_dir_set is not None:
-        return _cache_dir_set
-    if cache_dir is None:
-        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                   DEFAULT_CACHE_DIR)
-    try:
-        import jax
-        from jax.experimental.compilation_cache import compilation_cache
-        os.makedirs(cache_dir, exist_ok=True)
-        compilation_cache.set_cache_dir(cache_dir)
-        # smoke-scale ticks compile in well under the default 1s floor;
-        # cache them anyway — the point is cold-start tick rate, not disk
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                         ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass                       # older jax: keep its defaults
-        _cache_dir_set = cache_dir
-        return cache_dir
-    except Exception as e:                 # pragma: no cover - best effort
-        print(f"persistent compilation cache unavailable: {e}")
-        return None
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or CHECKOUT_CACHE_DIR
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # smoke-scale ticks compile in well under the default 1s floor; cache
+    # them anyway — the point is cold-start tick rate, not disk
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir

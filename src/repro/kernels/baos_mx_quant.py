@@ -28,23 +28,9 @@ def _quant_block(xs: jax.Array, fmt: mx_lib.MXFormat, block: int):
     """xs (TILE_S, D) -> fake-quantized, blocks of `block` along D."""
     t, d = xs.shape
     xb = xs.reshape(t, d // block, block)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    safe = jnp.where(amax > 0, amax, 1.0)
-    # ceil/grid_max rule — must match core/mx._shared_scale exactly
-    e = jnp.clip(jnp.ceil(jnp.log2(safe / fmt.grid_max)), -127.0, 127.0)
-    scale = jnp.where(amax > 0, jnp.exp2(e), 1.0)
-    y = xb / scale
-    if fmt.is_int:
-        lo = -(2.0 ** (fmt.element_bits - 1))
-        hi = 2.0 ** (fmt.element_bits - 1) - 1
-        q = jnp.clip(jnp.sign(y) * jnp.floor(jnp.abs(y) *
-                                             (2.0 ** fmt.frac_bits) + 0.5),
-                     lo, hi) * (2.0 ** -fmt.frac_bits)
-    else:
-        # e4m3 grid via saturating cast
-        q = jnp.clip(y, -448.0, 448.0).astype(jnp.float8_e4m3fn
-                                              ).astype(jnp.float32)
-    return (q * scale).reshape(t, d)
+    q = mx_lib._quant_blocks(
+        xb, jnp.max(jnp.abs(xb), axis=-1, keepdims=True), fmt)
+    return q.reshape(t, d)
 
 
 def _kernel(x_ref, c_ref, f_ref, out_ref, *, fmt: mx_lib.MXFormat,

@@ -44,6 +44,23 @@ SUPPORTED_FMTS = ("none", "bf16", "mxfp8_e4m3")
 _MX_BLOCK = mx.MX_BLOCK
 
 
+def _block_amax(a: jax.Array, block: int) -> jax.Array:
+    """Max of each aligned ``block``-lane group of ``a`` (r, c), broadcast
+    back over the group's lanes.  A log2(block)-step XOR butterfly of lane
+    rotations: Mosaic cannot lay out the (r, c/block, block) reshape the
+    jnp formulation uses, but rotates lanes natively.  Exact (a max), so
+    the shared scales match mx's reshape-based ones bit for bit."""
+    c = a.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    s = 1
+    while s < block:
+        below = pltpu.roll(a, s, 1)          # a[lane - s]
+        above = pltpu.roll(a, c - s, 1)      # a[lane + s]
+        a = jnp.maximum(a, jnp.where((lane & s) != 0, below, above))
+        s *= 2
+    return a
+
+
 def _fake_quant_tile(z: jax.Array, fmt: str, model_dtype) -> jax.Array:
     """Per-tile mirror of core/mx.mx_fake_quant for the sampling formats.
 
@@ -59,12 +76,8 @@ def _fake_quant_tile(z: jax.Array, fmt: str, model_dtype) -> jax.Array:
         return z.astype(jnp.bfloat16).astype(model_dtype).astype(jnp.float32)
     if fmt == "mxfp8_e4m3":
         fmt_o = mx.FORMATS[fmt]
-        r, c = z.shape
-        xb = z.reshape(r, c // _MX_BLOCK, _MX_BLOCK)
-        amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-        scale = mx._shared_scale(amax, fmt_o)
-        q = mx._quant_element(xb / scale, fmt_o) * scale
-        return q.reshape(r, c).astype(model_dtype).astype(jnp.float32)
+        q = mx._quant_blocks(z, _block_amax(jnp.abs(z), _MX_BLOCK), fmt_o)
+        return q.astype(model_dtype).astype(jnp.float32)
     raise ValueError(f"unsupported sampling fmt for the fused kernel: {fmt}")
 
 
@@ -95,28 +108,32 @@ def _kernel(seed_ref, h_ref, w_ref, conf_ref, idx_ref,
     if suppress_id is not None:
         z = jnp.where(col == suppress_id, NEG, z)        # V_RED skip
 
-    local_m = jnp.max(z, axis=-1)                        # V_RED_MAX
+    # per-row state is (TILE_R, 1): Mosaic tiles a rank-1 block only when
+    # it spans the array or a multiple of 128 rows
+    local_m = jnp.max(z, axis=-1, keepdims=True)         # V_RED_MAX
     big = jnp.int32(2 ** 30)
     m_old, s_old = m_sc[...], s_sc[...]
     m_new = jnp.maximum(m_old, local_m)
-    s_new = s_old * jnp.exp(m_old - m_new) + \
-        jnp.sum(jnp.exp(z - m_new[:, None]), axis=-1)    # V_EXP_V + V_RED_SUM
+    s_new = s_old * jnp.exp(m_old - m_new) + jnp.sum(   # V_EXP_V + V_RED_SUM
+        jnp.exp(z - m_new), axis=-1, keepdims=True)
     m_sc[...], s_sc[...] = m_new, s_new
 
     if temperature > 0.0:
         rows = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0) + r * tile_r
         g = sampling_lib.counter_gumbel(seed_ref[0, 0], rows, col)
         sc = z / temperature + g                         # Gumbel-max trick
-        local_b = jnp.max(sc, axis=-1)
-        li = jnp.min(jnp.where(sc >= local_b[:, None], col, big), axis=-1)
-        z_li = jnp.max(jnp.where(col == li[:, None], z, NEG), axis=-1)
+        local_b = jnp.max(sc, axis=-1, keepdims=True)
+        li = jnp.min(jnp.where(sc >= local_b, col, big), axis=-1,
+                     keepdims=True)
+        z_li = jnp.max(jnp.where(col == li, z, NEG), axis=-1, keepdims=True)
         upd = local_b > b_sc[...]
         b_sc[...] = jnp.where(upd, local_b, b_sc[...])
         i_sc[...] = jnp.where(upd, li, i_sc[...])
         z_sc[...] = jnp.where(upd, z_li, z_sc[...])
     else:
         # first-occurrence argmax (matches jnp.argmax tie-breaking)
-        local_i = jnp.min(jnp.where(z >= local_m[:, None], col, big), axis=-1)
+        local_i = jnp.min(jnp.where(z >= local_m, col, big), axis=-1,
+                          keepdims=True)
         i_sc[...] = jnp.where(local_m > m_old, local_i, i_sc[...])
 
     @pl.when(c == n_chunks - 1)
@@ -168,15 +185,15 @@ def fused_head_sampling(hidden: jax.Array, w_head: jax.Array,
                                memory_space=pltpu.SMEM),
                   pl.BlockSpec((tile_r, d), lambda r, c: (r, 0)),
                   pl.BlockSpec((d, chunk_v), lambda r, c: (0, c))],
-        out_specs=[pl.BlockSpec((tile_r,), lambda r, c: (r,)),
-                   pl.BlockSpec((tile_r,), lambda r, c: (r,))],
-        out_shape=[jax.ShapeDtypeStruct((Rp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Rp,), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((tile_r,), jnp.float32),
-                        pltpu.VMEM((tile_r,), jnp.float32),
-                        pltpu.VMEM((tile_r,), jnp.int32),
-                        pltpu.VMEM((tile_r,), jnp.float32),
-                        pltpu.VMEM((tile_r,), jnp.float32)],
+        out_specs=[pl.BlockSpec((tile_r, 1), lambda r, c: (r, 0)),
+                   pl.BlockSpec((tile_r, 1), lambda r, c: (r, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((Rp, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((tile_r, 1), jnp.float32),
+                        pltpu.VMEM((tile_r, 1), jnp.float32),
+                        pltpu.VMEM((tile_r, 1), jnp.int32),
+                        pltpu.VMEM((tile_r, 1), jnp.float32),
+                        pltpu.VMEM((tile_r, 1), jnp.float32)],
         interpret=interpret,
     )(seed.reshape(1, 1).astype(jnp.uint32), hidden, w_head)
-    return conf[:R], idx[:R]
+    return conf[:R, 0], idx[:R, 0]

@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (kernels execute via the Pallas
+``interpret`` defaults to True off the TPU (kernels execute via the Pallas
 interpreter for correctness validation) and False on TPU (compiled
-Mosaic).  Model code selects kernels vs XLA reference via config flags;
-the dry-run lowers the XLA path (Pallas cannot lower for TPU from a CPU
-host), which is recorded in DESIGN.md.
+Mosaic).  A CPU host can still compile them for a TPU it does not have:
+tests/test_tpu_compile.py lowers the main-path kernels for a described
+v5e topology, which is where Mosaic's layout and VMEM refusals surface.
 """
 from __future__ import annotations
 

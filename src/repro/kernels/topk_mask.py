@@ -25,7 +25,7 @@ NEG = -1e30  # python float: pallas kernels cannot capture array constants
 def _kernel(conf_ref, mask_ref, k_ref, out_ref):
     c = conf_ref[...].astype(jnp.float32)            # (TILE_R, L)
     m = mask_ref[...] > 0                            # (TILE_R, L)
-    k = k_ref[...]                                   # (TILE_R,)
+    k = k_ref[...]                                   # (TILE_R, 1)
     c = jnp.where(m, c, NEG)
 
     ci = c[:, :, None]                               # (R, L, 1) "self"
@@ -35,8 +35,9 @@ def _kernel(conf_ref, mask_ref, k_ref, out_ref):
     gt = (cj > ci) | ((cj == ci) & (jj < ii))        # stable descending rank
     rank = jnp.sum(gt.astype(jnp.int32), axis=2)     # (R, L)
 
-    take = jnp.minimum(k, jnp.sum(m.astype(jnp.int32), axis=-1))
-    out = (rank < take[:, None]) & m
+    take = jnp.minimum(k, jnp.sum(m.astype(jnp.int32), axis=-1,
+                                  keepdims=True))
+    out = (rank < take) & m
     out_ref[...] = out.astype(jnp.int32)
 
 
@@ -57,9 +58,11 @@ def topk_mask(conf: jax.Array, mask: jax.Array, k: jax.Array, *,
         grid=(Rp // tile_r,),
         in_specs=[pl.BlockSpec((tile_r, L), lambda r: (r, 0)),
                   pl.BlockSpec((tile_r, L), lambda r: (r, 0)),
-                  pl.BlockSpec((tile_r,), lambda r: (r,))],
+                  # (TILE_R, 1): Mosaic tiles a rank-1 block only when it
+                  # spans the array or a multiple of 128 rows
+                  pl.BlockSpec((tile_r, 1), lambda r: (r, 0))],
         out_specs=pl.BlockSpec((tile_r, L), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, L), jnp.int32),
         interpret=interpret,
-    )(conf, mask.astype(jnp.int32), k.astype(jnp.int32))
+    )(conf, mask.astype(jnp.int32), k.astype(jnp.int32)[:, None])
     return out[:R]

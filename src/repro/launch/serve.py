@@ -83,10 +83,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "while_loop megastep (docs/megatick.md): one "
                          "dispatch + one host sync per megastep instead "
                          "of per tick; incompatible with --breakdown")
-    ap.add_argument("--compilation-cache-dir", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(default $JAX_COMPILATION_CACHE_DIR or "
-                         "~/.cache/repro-xla)")
     # online streaming frontend (docs/streaming_serving.md)
     ap.add_argument("--http", type=int, default=None, metavar="PORT",
                     help="serve the streaming HTTP API on this port "
@@ -347,12 +343,10 @@ def make_mesh_arg(spec: str):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-    # deployment hygiene before the first computation: tuned XLA flags
-    # only apply pre-backend-init, and arming the persistent compilation
-    # cache early lets warmup hit it (docs/megatick.md)
+    # arm the persistent compilation cache before the first compile so
+    # warmup hits it (docs/megatick.md)
     from repro import deploy
-    deploy.setup_xla_flags()
-    deploy.ensure_compilation_cache(args.compilation_cache_dir)
+    deploy.ensure_compilation_cache()
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))

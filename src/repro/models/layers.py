@@ -103,7 +103,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0
 # Bidirectional chunked attention (online softmax over KV chunks)
 # ---------------------------------------------------------------------------
 
-NEG_INF = jnp.float32(-1e30)
+NEG_INF = -1e30  # python float: importing builds no device array
 
 
 def _mask_bias(q_pos: jax.Array, kv_pos: jax.Array, kv_valid: jax.Array,

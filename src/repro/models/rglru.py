@@ -321,7 +321,7 @@ class GriffinModel:
             calib = None
             attn_out = layers.attention(
                 q, k, v, q_pos=positions, kv_pos=positions,
-                kv_valid=jnp.ones((B, S), bool), mode="bidir",
+                kv_valid=kv_valid, mode="bidir",
                 window=cfg.window, kv_chunk=cfg.attn_chunk,
                 unroll=cfg.unroll_layers)
         attn_out = attn_out.reshape(B, S, cfg.n_heads * cfg.d_head)
@@ -354,7 +354,9 @@ class GriffinModel:
             if kv_valid is None:
                 kv_valid = jnp.ones((B, s_tot), bool)
         else:
-            kv_pos, kv_valid = positions, jnp.ones((B, S), bool)
+            kv_pos = positions
+            if kv_valid is None:
+                kv_valid = jnp.ones((B, S), bool)
 
         def rec_state(lstate, lconv):
             if cache is None:

@@ -396,10 +396,11 @@ def _layer(x, lp, lcache, cfg: ModelConfig, *, seg_start, positions,
                     "k_act": k_act.astype(lcache["k_act"].dtype),
                     "v_act": v_act.astype(lcache["v_act"].dtype)})
     else:
-        val = jnp.ones((B, S), bool)
+        # cache-free segment: the keys are this segment's own positions,
+        # and kv_valid hides the canvas padding past each row's length
         attn_out = layers.attention(
             q, k_seg, v_seg, q_pos=positions, kv_pos=positions,
-            kv_valid=val, mode=mode, window=cfg.window,
+            kv_valid=kv_valid, mode=mode, window=cfg.window,
             kv_chunk=cfg.attn_chunk, unroll=cfg.unroll_layers,
             score_dtype=cfg.jscore_dtype)
         new_cache = None

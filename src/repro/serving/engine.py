@@ -522,11 +522,11 @@ class ServingEngine:
                 self.pool.bind_row(slot, row, pick.prompt_len,
                                    pick.total_len)
             else:
-                # re-pin: the eager scatter's output sharding drifts from
-                # the tick's P('data', None) spec, which would retrigger a
-                # jit compile on the first timed tick after warmup()
-                self.x = self._put_rows(
-                    self.x.at[slot].set(jnp.asarray(row)))
+                # pinned to the tick's P('data', None) spec: a drifting
+                # output sharding would retrigger a jit compile on the
+                # first timed tick after warmup()
+                self.x = self.x.at[slot].set(
+                    jnp.asarray(row), out_sharding=self._row_sharding)
             self._valid_np[slot] = np.arange(self.max_seq_len) < pick.total_len
             self._kv_dirty = True      # uploaded once per tick, not per admit
             self.metrics.request_admitted(pick.uid, self.now)
@@ -701,15 +701,14 @@ class ServingEngine:
         untouched — so the first *timed* tick charges no jit compile time
         to ``now`` (latency percentiles / tokens_per_s stay clean).
 
-        Compiles land in the persistent compilation cache
-        (repro.deploy, docs/megatick.md), so later processes warm up from
-        disk.  With ``megatick_k > 1`` both the K=1 tick *and* the
+        Where the entry point armed the persistent compilation cache
+        (repro.deploy, docs/megatick.md), compiles land there and later
+        processes warm up from disk.  With ``megatick_k > 1`` both the K=1
+        tick *and* the
         configured megatick shape pre-compile, and the megatick warmup
         runs on throwaway *copies* of the canvas/cache — its jitted
         executable donates those buffers, and warmup must leave engine
         state untouched."""
-        from repro import deploy
-        deploy.ensure_compilation_cache()
         self._flush_kv_valid()
         B = self.num_slots
         bs = jnp.zeros((B,), jnp.int32)

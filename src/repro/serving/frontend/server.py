@@ -387,8 +387,9 @@ def build_frontend(model, params, dcfg, *, model_name: str,
                    slo_classes=None) -> ServeFrontend:
     """Wire engines -> workers -> router -> frontend.  One independent
     engine per replica (each with its own slot pool, rng chain, and tick
-    thread; params are shared read-only, and the jitted tick executable is
-    shared through the get_tick_fn cache).
+    thread; without a mesh, replica i's params live on local device
+    i mod n, and the jitted tick executable is shared through the
+    get_tick_fn cache).
 
     Observability: ``obs`` (default: a fresh :class:`ServingObs` root) is
     fanned out as per-replica labeled views, so one ``/metrics`` scrape
@@ -433,12 +434,17 @@ def build_frontend(model, params, dcfg, *, model_name: str,
             print(f"drift monitor disabled (no analytical model): {e}")
     host_stages = ("dispatch", "device_sync") + (
         ("paged_io",) if paged else ())
+    # without a mesh, replica i holds its params on local device i (round
+    # robin); the engine's state follows its committed params there
+    devices = jax.local_devices() if mesh is None else None
     workers = []
     for i in range(replicas):
         rep_obs = obs.for_replica(f"replica-{i}")
         if modeled is not None:
             rep_obs.set_drift_model(modeled, host_stages=host_stages)
-        eng = ServingEngine(model, params, dcfg, EngineConfig(
+        rep_params = params if devices is None else jax.device_put(
+            params, devices[i % len(devices)])
+        eng = ServingEngine(model, rep_params, dcfg, EngineConfig(
             num_slots=num_slots, max_seq_len=max_seq_len, mode=mode,
             policy=policy, mesh=mesh, rng=jax.random.PRNGKey(seed + i),
             breakdown=breakdown, obs=rep_obs, megatick_k=megatick_k,

@@ -431,7 +431,6 @@ class TestJaxprAudit:
     def test_undeclared_collective_axis_fires(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.analysis import jaxpr_audit
@@ -440,7 +439,7 @@ class TestJaxprAudit:
         mesh = make_debug_mesh(1, 1)
 
         def red(x):
-            return shard_map(lambda v: jax.lax.psum(v, "model"),
+            return jax.shard_map(lambda v: jax.lax.psum(v, "model"),
                              mesh=mesh, in_specs=P(None, "model"),
                              out_specs=P())(x)
 

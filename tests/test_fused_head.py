@@ -269,9 +269,9 @@ def test_fused_step_without_rng_is_greedy_on_both_backends(setup):
 
 
 def test_kernel_unsupported_fmt_falls_back_to_oracle(setup):
-    """Sampling formats outside the kernel's set (e.g. mxint8) must route
-    to the lax.scan oracle even when the kernel path is requested, instead
-    of raising only on TPU backends."""
+    """Sampling formats outside the kernel's set (e.g. mxint8) run on the
+    lax.scan oracle off the chip; where the kernel path is taken (the
+    default on TPU) they raise instead of quietly leaving the kernel."""
     cfg, model, params, _ = setup
     h = jax.random.normal(jax.random.PRNGKey(8), (2, 8, cfg.d_model)) * 0.5
     w = params["lm_head"]
@@ -281,8 +281,11 @@ def test_kernel_unsupported_fmt_falls_back_to_oracle(setup):
     x_ref, _, _ = sampling.sampling_step_full(
         sampling.head_logits(h, w), x, cfg.mask_id, k, scfg)
     x_fus, _, _ = sampling.fused_sampling_step_full(
-        h, w, x, cfg.mask_id, k, scfg, chunk_v=96, use_kernel=True)
+        h, w, x, cfg.mask_id, k, scfg, chunk_v=96, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(x_ref), np.asarray(x_fus))
+    with pytest.raises(ValueError, match="mxint8"):
+        sampling.fused_sampling_step_full(
+            h, w, x, cfg.mask_id, k, scfg, chunk_v=96, use_kernel=True)
 
 
 def test_quant_policy_reaches_jitted_ticks(setup):

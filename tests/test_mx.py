@@ -92,3 +92,45 @@ def test_storage_bytes():
     assert mx.storage_bytes((64,), "mxint8") == 64 + 2
     assert mx.storage_bytes((64,), "mxint4") == 32 + 2
     assert mx.storage_bytes((4, 64), "bf16") == 512
+
+
+def test_mxfp8_element_rounding_matches_float8_cast():
+    """The spelled-out e4m3 rounding is ml_dtypes' saturating
+    float8_e4m3fn cast bit for bit: normals, subnormals (step 2^-9),
+    ties to even, saturation at 448, signs and zero."""
+    import ml_dtypes
+    rs = np.random.RandomState(0)
+    grid = np.arange(256, dtype=np.uint8).view(ml_dtypes.float8_e4m3fn)
+    grid = grid.astype(np.float32)
+    grid = np.unique(grid[np.isfinite(grid)])
+    mids = (grid[1:] + grid[:-1]) / 2                 # exact ties
+    x = np.concatenate([
+        grid, mids, np.nextafter(mids, np.float32(np.inf)),
+        np.nextafter(mids, np.float32(-np.inf)),
+        rs.uniform(-500, 500, 4096), rs.uniform(-0.03, 0.03, 4096),
+        [0.0, -0.0, 447.9, 448.0, 460.0, -1e4]]).astype(np.float32)
+    got = np.asarray(jax.jit(lambda v: mx._quant_element(v, mx.MXFP8))(x))
+    ref = np.clip(x, -448, 448).astype(ml_dtypes.float8_e4m3fn)
+    np.testing.assert_array_equal(got, ref.astype(np.float32))
+
+
+@pytest.mark.parametrize("fmt", FMTS)
+def test_shared_scale_is_smallest_exact_power_of_two(fmt):
+    """scale = 2^e exactly, with e the smallest exponent for which
+    amax / 2^e <= grid_max — also where amax is grid_max * 2^k exactly
+    and one ulp either side of it."""
+    f = mx.FORMATS[fmt]
+    rs = np.random.RandomState(1)
+    edge = np.float32(f.grid_max) * np.exp2(np.arange(-30, 30)).astype(
+        np.float32)
+    amax = np.concatenate([
+        edge, np.nextafter(edge, np.float32(np.inf)),
+        np.nextafter(edge, np.float32(0)),
+        np.exp2(rs.uniform(-40, 40, 2048))]).astype(np.float32)
+    scale = np.asarray(jax.jit(
+        lambda a: mx._pow2(mx._shared_exp(a, f)))(amax))
+    e = np.log2(scale.astype(np.float64))
+    np.testing.assert_array_equal(e, np.round(e))     # exact powers of two
+    a64, g = amax.astype(np.float64), float(f.grid_max)
+    assert (a64 / scale <= g).all()
+    assert (a64 / (scale / 2.0) > g).all()            # one step smaller fails

@@ -10,8 +10,9 @@ import pytest
 from repro.configs import base
 from repro.core import diffusion
 from repro.models.registry import build_model
-from repro.serving import (CachePool, FIFOPolicy, Request, ServingEngine,
-                           ShortestGenFirstPolicy, SlowFastPolicy, get_policy)
+from repro.serving import (CachePool, EngineConfig, FIFOPolicy, Request,
+                           ServingEngine, ShortestGenFirstPolicy,
+                           SlowFastPolicy, get_policy)
 
 
 @pytest.fixture(scope="module")
@@ -93,6 +94,22 @@ def test_engine_bit_identical_to_generate_single_request(setup):
     done = eng.run([Request(uid=1, prompt=np.asarray(prompt[0]),
                             gen_length=16)])
     assert len(done) == 1
+    np.testing.assert_array_equal(done[0].tokens, np.asarray(ref[0]))
+
+
+@pytest.mark.parametrize("max_seq_len", [32, 48, 64])
+def test_engine_tokens_independent_of_canvas_padding(setup, max_seq_len):
+    """A request's tokens do not depend on how much masked canvas padding
+    follows it: the padded positions are hidden from attention by
+    kv_valid, so every max_seq_len gives generate()'s tokens."""
+    cfg, model, params = setup
+    dcfg = _dcfg("none")
+    prompt = _prompt(cfg, 5, 16)
+    ref = diffusion.generate(model, params, prompt, dcfg,
+                             rng=jax.random.PRNGKey(11))
+    eng = ServingEngine(model, params, dcfg, EngineConfig(
+        num_slots=1, max_seq_len=max_seq_len, mode="none"))
+    done = eng.run([Request(prompt=np.asarray(prompt[0]), gen_length=16)])
     np.testing.assert_array_equal(done[0].tokens, np.asarray(ref[0]))
 
 

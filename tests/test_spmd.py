@@ -100,7 +100,6 @@ def test_sharded_stable_max_matches_dense(setup):
     """The combine primitives under an explicit shard_map reproduce dense
     stable_max over an uneven (padded) vocab."""
     _skip_unless(4)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     V, d = 257, 32
     h = jax.random.normal(jax.random.PRNGKey(0), (8, d), jnp.float32)
@@ -116,7 +115,7 @@ def test_sharded_stable_max_matches_dense(setup):
             h, w_shard, "model", "mxfp8_e4m3", suppress_id=V - 1,
             col_limit=V)
 
-    conf, idx = jax.jit(shard_map(
+    conf, idx = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(None, "model")),
         out_specs=(P(), P())))(h, wp)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx_ref))

@@ -1,4 +1,5 @@
 """End-to-end behaviour tests for the full system (paper pipeline)."""
+import os
 import subprocess
 import sys
 
@@ -11,6 +12,20 @@ from repro.configs import base
 from repro.core import baos as baos_lib
 from repro.core import diffusion, sampling
 from repro.models.registry import build_model
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _child_env(tmp_path) -> dict:
+    """The CLI child inherits this environment, stays on the CPU (another
+    process may hold the TPU library), and keeps any compilation cache
+    out of the checkout."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH")) if p)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla_cache")
+    return env
 
 
 def test_full_dart_pipeline_quality_preserved():
@@ -102,38 +117,35 @@ def test_multi_block_generation_uses_committed_context(cache):
     assert acc > 0.3, f"continuation acc {acc}"
 
 
-def test_train_driver_cli():
+def test_train_driver_cli(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "qwen2-0.5b",
          "--steps", "6", "--batch", "2", "--seq", "32",
-         "--ckpt-dir", "/tmp/test_train_cli"],
+         "--ckpt-dir", str(tmp_path / "ckpt")],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo")
+        env=_child_env(tmp_path), cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "done:" in out.stdout
 
 
-def test_serve_driver_cli():
+def test_serve_driver_cli(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen2-0.5b",
          "--batch", "2", "--prompt-len", "16", "--gen-len", "16",
          "--block-len", "8", "--steps", "4", "--requests", "2"],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo")
+        env=_child_env(tmp_path), cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "steady-state TPS" in out.stdout
 
 
-def test_train_driver_failure_recovery_cli():
+def test_train_driver_failure_recovery_cli(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "qwen2-0.5b",
          "--steps", "10", "--batch", "2", "--seq", "32", "--ckpt-every", "3",
          "--inject-failure-at", "5",
-         "--ckpt-dir", "/tmp/test_train_cli_fail"],
+         "--ckpt-dir", str(tmp_path / "ckpt")],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo")
+        env=_child_env(tmp_path), cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "restarts=1" in out.stdout
