@@ -2,7 +2,8 @@
 
 The obs hooks (repro.obs.serving.ServingObs) run on the engine tick path:
 per-stage histogram observes, lifecycle counters, drift accumulation, and
-(when tracing) back-dated span emission.  The design claim
+(when tracing) the tick span and slot counter; the engine's dllm.* stage
+spans run in every configuration, so the A/B below includes them.  The design claim
 (docs/observability.md) is that all of it is host-side bookkeeping over
 numbers the tick already computed — no extra device syncs — so the
 per-tick cost must disappear into the millisecond-scale tick.
@@ -119,7 +120,7 @@ def _run_once(cfg, model, params, dcfg, obs) -> list:
 def _hook_cost_s(obs) -> float:
     """Median seconds of one tick's worth of obs hook calls, measured in
     isolation: the stage/tick histograms + gauges + drift feed + (when
-    tracing) the back-dated span emission, plus the typical per-tick
+    tracing) the tick span and slot counter, plus the typical per-tick
     lifecycle traffic (one tokens_committed + one kv upload)."""
     import time
     stages = {"host_prep": 2e-4, "dispatch": 5e-4, "device_sync": 1e-4,
@@ -237,7 +238,7 @@ def run() -> list:
     hook_s = {name: _hook_cost_s(configs[name]())
               for name in ("metrics", "trace")}
     # worst case for megatick: tracing on, so each megastep also emits the
-    # megastep span and K back-dated tick spans
+    # megastep span and K tick spans
     hook_s["megatick"] = _hook_cost_megatick_s(configs["trace"]())
     hook_s["events"] = _hook_cost_events_s()
     hook_s["trace_ctx"] = _hook_cost_trace_ctx_s()

@@ -52,7 +52,8 @@ HOT_PATHS: Dict[str, object] = {
         "warm_step", "refine_step", "_active_sampling_step",
         "_cached_commit_fn", "_cached_step_fn", "tick_forward",
         "tick_sample", "batched_tick", "get_tick_fn", "get_spmd_tick_fn",
-        "megatick_state", "get_megatick_fn", "get_tick_stage_fns",
+        "megatick_state", "_megatick_commit", "get_megatick_fn",
+        "get_tick_stage_fns",
         "gather_canvas_rows", "scatter_canvas_rows", "_gather_pages_axis1",
         "_scatter_pages_axis1", "gather_cache_rows", "scatter_cache_rows",
         "get_paged_tick_fn", "get_paged_megatick_fn",

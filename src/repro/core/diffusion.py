@@ -412,35 +412,36 @@ def tick_forward(model, params, x: jax.Array, kv_valid: jax.Array,
     (unfused) or never materialized at all (fused).  Legacy models return
     full-sequence logits as before.
     """
-    B, s_tot = x.shape
-    L = dcfg.block_length
-    mode = head_feed_mode(model, dcfg)
-    extra = {} if mode == "logits" else {"head_mode": "hidden"}
-    if trace_lib.is_active():
-        # opaque transformer marker (costed by the analytical per-phase
-        # model in the hybrid e2e); the legacy path's full-sequence head
-        # GEMM + logits writeback is the one head cost paid in-forward, so
-        # it is charged here rather than in the sampling stage
-        trace_lib.emit("XU_FORWARD", (B, s_tot, int(model.cfg.d_model)),
-                       stage="forward", note=f"cache={cache is not None}")
-        if mode == "logits":
-            trace_lib.emit_legacy_head(B * s_tot, int(model.cfg.d_model),
-                                       int(model.cfg.vocab))
-    if cache is None:
-        feats, _, _ = model.forward(
-            params, tokens=x, cache=None, seg_start=0, kv_valid=kv_valid,
+    with jax.named_scope("forward"):
+        B, s_tot = x.shape
+        L = dcfg.block_length
+        mode = head_feed_mode(model, dcfg)
+        extra = {} if mode == "logits" else {"head_mode": "hidden"}
+        if trace_lib.is_active():
+            # opaque transformer marker (costed by the analytical per-phase
+            # model in the hybrid e2e); the legacy path's full-sequence head
+            # GEMM + logits writeback is the one head cost paid in-forward, so
+            # it is charged here rather than in the sampling stage
+            trace_lib.emit("XU_FORWARD", (B, s_tot, int(model.cfg.d_model)),
+                           stage="forward", note=f"cache={cache is not None}")
+            if mode == "logits":
+                trace_lib.emit_legacy_head(B * s_tot, int(model.cfg.d_model),
+                                           int(model.cfg.vocab))
+        if cache is None:
+            feats, _, _ = model.forward(
+                params, tokens=x, cache=None, seg_start=0, kv_valid=kv_valid,
+                quant=quant, **extra, **fwd_kw)
+            return feats, None
+        calib_mask = None
+        if dcfg.baos.calib_scope == "active_block":
+            pos = jnp.arange(s_tot, dtype=jnp.int32)[None, :]
+            calib_mask = ((pos >= block_start[:, None]) &
+                          (pos < block_start[:, None] + L))
+        feats, new_cache, _ = model.forward(
+            params, tokens=x, cache=cache, seg_start=0, kv_valid=kv_valid,
+            baos_cfg=dcfg.baos, calibrate=True, calib_mask=calib_mask,
             quant=quant, **extra, **fwd_kw)
-        return feats, None
-    calib_mask = None
-    if dcfg.baos.calib_scope == "active_block":
-        pos = jnp.arange(s_tot, dtype=jnp.int32)[None, :]
-        calib_mask = ((pos >= block_start[:, None]) &
-                      (pos < block_start[:, None] + L))
-    feats, new_cache, _ = model.forward(
-        params, tokens=x, cache=cache, seg_start=0, kv_valid=kv_valid,
-        baos_cfg=dcfg.baos, calibrate=True, calib_mask=calib_mask,
-        quant=quant, **extra, **fwd_kw)
-    return feats, new_cache
+        return feats, new_cache
 
 
 def tick_sample(params, feats: jax.Array, x: jax.Array,
@@ -456,6 +457,11 @@ def tick_sample(params, feats: jax.Array, x: jax.Array,
     Stable-Max confidence over the tokens committed this tick (+inf when
     none) — the SlowFast early-exit signal — and masks_left counts masked
     positions remaining in each row's active block.
+
+    The slice, head, Stable-Max and top-k run under the ``sampling`` name
+    scope, the scatter back and the two per-row results under ``commit``
+    (``forward`` is ``tick_forward``'s): the profiler's op metadata then
+    splits the fused tick's device time into the three stages.
     """
     L = dcfg.block_length
     mode = head_feed_mode(model, dcfg) if model is not None else "logits"
@@ -463,16 +469,18 @@ def tick_sample(params, feats: jax.Array, x: jax.Array,
     def row_slice(a, s):
         return jax.lax.dynamic_slice_in_dim(a, s, L, axis=0)
 
-    fa = jax.vmap(row_slice)(feats, block_start)   # (B, L, d) or (B, L, V)
-    xa = jax.vmap(row_slice)(x, block_start)
-    xa_new, transfer, conf = _active_sampling_step(
-        fa, xa, k, srng, params, mode, dcfg, mask_id, model, quant=quant,
-        axis_name=axis_name)
-    x_new = jax.vmap(
-        lambda row, upd, s: jax.lax.dynamic_update_slice_in_dim(
-            row, upd, s, axis=0))(x, xa_new, block_start)
-    conf_min = jnp.min(jnp.where(transfer, conf, jnp.inf), axis=-1)
-    masks_left = jnp.sum(xa_new == mask_id, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sampling"):
+        fa = jax.vmap(row_slice)(feats, block_start)  # (B,L,d) or (B,L,V)
+        xa = jax.vmap(row_slice)(x, block_start)
+        xa_new, transfer, conf = _active_sampling_step(
+            fa, xa, k, srng, params, mode, dcfg, mask_id, model, quant=quant,
+            axis_name=axis_name)
+    with jax.named_scope("commit"):
+        x_new = jax.vmap(
+            lambda row, upd, s: jax.lax.dynamic_update_slice_in_dim(
+                row, upd, s, axis=0))(x, xa_new, block_start)
+        conf_min = jnp.min(jnp.where(transfer, conf, jnp.inf), axis=-1)
+        masks_left = jnp.sum(xa_new == mask_id, axis=-1).astype(jnp.int32)
     return x_new, conf_min, masks_left
 
 
@@ -638,6 +646,43 @@ def megatick_state(prompt_len, gen_blocks, dcfg: DiffusionConfig,
     }
 
 
+def _megatick_commit(st, bufs, i, x_new, conf_min, masks_left, bs, k,
+                     early, stop_on_release, L: int, axis_name):
+    """The megatick iteration's commit: per-row scheduler state after the
+    tick, its record in the ``i``-th slot of the commit buffers, and the
+    loop's stop flag."""
+    bi, t, act = st["block_idx"], st["step_in_block"], st["active"]
+    boundary = act & (masks_left == 0)
+    released = boundary & (bi + 1 >= st["gen_blocks"])
+    st2 = dict(st)
+    st2["block_idx"] = jnp.where(boundary, bi + 1, bi)
+    st2["step_in_block"] = jnp.where(act, jnp.where(boundary, 0, t + 1), t)
+    st2["last_conf"] = jnp.where(
+        act, jnp.where(boundary, -jnp.inf, conf_min), st["last_conf"])
+    st2["block_masks_left"] = jnp.where(
+        act, jnp.where(boundary, L, masks_left), st["block_masks_left"])
+    st2["active"] = act & jnp.logical_not(released)
+
+    def row_slice(a, s):
+        return jax.lax.dynamic_slice_in_dim(a, s, L, axis=0)
+
+    upd = {"xa": jax.vmap(row_slice)(x_new, bs), "block_start": bs,
+           "block_idx": bi, "step_in_block": t, "conf": conf_min,
+           "masks_left": jnp.where(act, masks_left, 0), "k": k,
+           "active": act, "released": released, "early": early}
+    bufs = {key: jax.lax.dynamic_update_index_in_dim(
+                bufs[key], upd[key].astype(bufs[key].dtype), i, 0)
+            for key in bufs}
+    any_active = jnp.any(st2["active"])
+    any_released = jnp.any(released)
+    if axis_name is not None:
+        any_active = jax.lax.psum(any_active.astype(jnp.int32), "data") > 0
+        any_released = jax.lax.psum(
+            any_released.astype(jnp.int32), "data") > 0
+    stop = jnp.logical_not(any_active) | (stop_on_release & any_released)
+    return st2, bufs, stop
+
+
 @functools.lru_cache(maxsize=16)
 def get_megatick_fn(model, dcfg: DiffusionConfig, mask_id: int, k_max: int,
                     mesh=None, jit_steps: bool = True, quant=None,
@@ -716,37 +761,10 @@ def get_megatick_fn(model, dcfg: DiffusionConfig, mask_id: int, k_max: int,
             x_new, conf_min, masks_left = tick_sample(
                 params, feats, x, bs, k, srng, dcfg, mask_id, model=model,
                 quant=quant, axis_name=axis_name)
-            boundary = act & (masks_left == 0)
-            released = boundary & (bi + 1 >= st["gen_blocks"])
-            st2 = dict(st)
-            st2["block_idx"] = jnp.where(boundary, bi + 1, bi)
-            st2["step_in_block"] = jnp.where(
-                act, jnp.where(boundary, 0, t + 1), t)
-            st2["last_conf"] = jnp.where(
-                act, jnp.where(boundary, -jnp.inf, conf_min), lc)
-            st2["block_masks_left"] = jnp.where(
-                act, jnp.where(boundary, L, masks_left), bml)
-            st2["active"] = act & jnp.logical_not(released)
-
-            def row_slice(a, s):
-                return jax.lax.dynamic_slice_in_dim(a, s, L, axis=0)
-
-            upd = {"xa": jax.vmap(row_slice)(x_new, bs), "block_start": bs,
-                   "block_idx": bi, "step_in_block": t, "conf": conf_min,
-                   "masks_left": jnp.where(act, masks_left, 0), "k": k,
-                   "active": act, "released": released, "early": early}
-            bufs = {key: jax.lax.dynamic_update_index_in_dim(
-                        bufs[key], upd[key].astype(bufs[key].dtype), i, 0)
-                    for key in bufs}
-            any_active = jnp.any(st2["active"])
-            any_released = jnp.any(released)
-            if axis_name is not None:
-                any_active = jax.lax.psum(
-                    any_active.astype(jnp.int32), "data") > 0
-                any_released = jax.lax.psum(
-                    any_released.astype(jnp.int32), "data") > 0
-            stop = (jnp.logical_not(any_active)
-                    | (stop_on_release & any_released))
+            with jax.named_scope("commit"):
+                st2, bufs, stop = _megatick_commit(
+                    st, bufs, i, x_new, conf_min, masks_left, bs, k, early,
+                    stop_on_release, L, axis_name)
             return (i + 1, stop, x_new, new_cache, rng, st2, bufs)
 
         carry = (jnp.int32(0), jnp.asarray(False), x, cache, rng,

@@ -195,5 +195,7 @@ def fused_head_sampling(hidden: jax.Array, w_head: jax.Array,
                         pltpu.VMEM((tile_r, 1), jnp.float32),
                         pltpu.VMEM((tile_r, 1), jnp.float32)],
         interpret=interpret,
+        # the profiler trace finds the head by this name
+        name="fused_head_sampling",
     )(seed.reshape(1, 1).astype(jnp.uint32), hidden, w_head)
     return conf[:R, 0], idx[:R, 0]

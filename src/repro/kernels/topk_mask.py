@@ -64,5 +64,6 @@ def topk_mask(conf: jax.Array, mask: jax.Array, k: jax.Array, *,
         out_specs=pl.BlockSpec((tile_r, L), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, L), jnp.int32),
         interpret=interpret,
+        name="topk_mask",
     )(conf, mask.astype(jnp.int32), k.astype(jnp.int32)[:, None])
     return out[:R]

@@ -35,11 +35,13 @@ Metric catalog (names/labels/units in docs/observability.md):
   dllm_http_requests_total{route,code}      HTTP frontend answers
   dllm_router_submits_total{replica}        requests routed to each replica
   dllm_router_overloaded_total{}            submissions every replica refused
+  dllm_delivery_seconds{replica}            histogram, engine commit -> its
+                                            SSE write
 
 The engine calls the ``on_*``/``tick`` hooks with data it already has in
-hand (stage timings, commit deltas), so instrumentation adds no device
-syncs and no extra clock reads — benchmarks/obs_overhead.py pins the
-total tick-path cost under 2%.
+hand (stage seconds from its ``dllm.*`` spans, commit deltas), so
+instrumentation adds no device syncs — benchmarks/obs_overhead.py pins
+the total tick-path cost under 2%.
 """
 from __future__ import annotations
 
@@ -455,9 +457,10 @@ class ServingObs:
     def tick(self, stage_seconds: Mapping[str, float], dt: float,
              active_slots: int, queued: int,
              t_start_us: Optional[float] = None) -> None:
-        """One engine tick: histogram the stage split, refresh gauges,
-        feed drift, and (when tracing) emit the tick span with the stage
-        sub-spans back-dated to the measured boundaries."""
+        """One engine tick: histogram the stage seconds (measured by the
+        engine's ``dllm.*`` stage spans, which also put each stage in the
+        trace), refresh gauges, feed drift, and (when tracing) add the
+        tick span and the slot counter."""
         self._tick_count += 1
         self._b_ticks.inc()
         self._b_tick_s.observe(dt)
@@ -477,27 +480,13 @@ class ServingObs:
                     or self._tick_count % self.drift_refresh_ticks == 0:
                 self._refresh_drift_gauges()
         if self.trace.enabled and t_start_us is not None:
-            # complete (ph X) events built in one list, one lock: the
-            # stage boundaries were measured by the engine, so tracing a
-            # tick re-reads no clocks
-            tr = self.trace
-            pid, tid = tr.pid, tr._tid()
-            t = t_start_us
-            evs = [{"ph": "X", "name": "tick", "cat": "engine",
-                    "ts": t_start_us, "dur": 0.0, "pid": pid, "tid": tid,
-                    "args": {"active_slots": active_slots,
-                             "queued": queued}}]
-            for stage, s in stage_seconds.items():
-                evs.append({"ph": "X", "name": stage, "cat": "engine",
-                            "ts": t, "dur": s * 1e6, "pid": pid,
-                            "tid": tid})
-                t += s * 1e6
-            evs[0]["dur"] = max(t - t_start_us, dt * 1e6)
-            evs.append({"ph": "C", "name": "slots", "cat": "engine",
-                        "ts": t_start_us, "pid": pid, "tid": tid,
-                        "args": {"active": active_slots,
-                                 "queued": queued}})
-            tr.emit_many(evs)
+            dur = max(sum(stage_seconds.values()), dt) * 1e6
+            self.trace.complete("tick", "engine", t_start_us, dur,
+                                args={"active_slots": active_slots,
+                                      "queued": queued})
+            self.trace.counter("slots", {"active": active_slots,
+                                         "queued": queued},
+                               cat="engine", ts=t_start_us)
 
     def _refresh_drift_gauges(self) -> None:
         handles = self._drift_handles
@@ -524,15 +513,12 @@ class ServingObs:
         ``k_req``) denoising ticks taking ``dt`` seconds end to end.  The
         per-tick attribution already flowed through :meth:`tick`; this
         records the dispatch-level shape (and, when tracing, a megastep
-        span the back-dated tick spans nest under)."""
+        span over the replayed ticks' spans)."""
         self._b_megasteps.inc()
         self._b_megastep_ticks.observe(n_ticks)
         if self.trace.enabled and t_start_us is not None:
-            tr = self.trace
-            tr.emit_many([{"ph": "X", "name": "megastep", "cat": "engine",
-                           "ts": t_start_us, "dur": dt * 1e6, "pid": tr.pid,
-                           "tid": tr._tid(),
-                           "args": {"n_ticks": n_ticks, "k_req": k_req}}])
+            self.trace.complete("megastep", "engine", t_start_us, dt * 1e6,
+                                args={"n_ticks": n_ticks, "k_req": k_req})
 
     def policy_early_exit(self, n: int = 1) -> None:
         if n > 0:
@@ -592,3 +578,13 @@ def frontend_metrics(registry: Registry):
         "dllm_router_overloaded_total",
         "Submissions refused by every replica (HTTP 429)", ())
     return http, submits, overloaded
+
+
+def delivery_histogram(registry: Registry):
+    """Seconds from an engine ``CommitEvent``'s creation on the worker
+    thread to its SSE write on the event-loop thread, per streamed commit
+    (created once per root registry)."""
+    return registry.histogram(
+        "dllm_delivery_seconds",
+        "Engine commit event to its SSE write on the event loop",
+        ("replica",), LATENCY_BUCKETS)

@@ -1,14 +1,21 @@
-"""Low-overhead span tracing emitting Chrome-trace / Perfetto JSON.
+"""Program spans on the profiler's clock, and a Chrome-trace / Perfetto
+JSON collector.
+
+:class:`Spans` is how the serving stack marks its host work (engine tick
+stages, the worker loop, SSE writes; docs/observability.md has the list).
+Each span opens a ``jax.profiler.TraceAnnotation``, so inside any profiler
+session (``--profile-ticks``, ``jax.profiler.start_trace``) it lands in
+the ``.xplane.pb`` beside the device ops; outside one it costs about a
+microsecond.  The span's measured seconds feed the tick-stage histogram
+(``dllm_tick_stage_seconds``, stages only) and, when ``--trace-out`` is
+on, one complete event in the collector below.
 
 The collector records Trace Event Format events (the JSON Perfetto and
-``chrome://tracing`` open natively, docs/observability.md):
+``chrome://tracing`` open natively):
 
-  * **Duration spans** (``ph: B``/``E``) for thread-local work — engine
-    tick phases, router hops.  Use :meth:`TraceCollector.span` (context
-    manager) or explicit :meth:`begin`/:meth:`end` with overridden
-    timestamps when the caller already measured the interval (the engine
-    times stages itself and emits the spans after the fact, so tracing
-    adds zero extra clock reads to the hot path).
+  * **Duration spans** — complete events (``ph: X``) from :class:`Spans`,
+    and ``B``/``E`` pairs from :meth:`TraceCollector.span` or explicit
+    :meth:`begin`/:meth:`end`.
   * **Async spans** (``ph: b``/``n``/``e``, keyed by ``id``) for work that
     crosses threads — the request lifecycle begins on the asyncio thread
     (queued), progresses on a replica worker thread (admitted,
@@ -31,12 +38,82 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 _CLOCK = time.perf_counter
+PREFIX = "dllm."
 
 
 def now_us() -> float:
     """Collector timebase: monotonic microseconds."""
     return _CLOCK() * 1e6
+
+
+class Span:
+    """One open span (see :class:`Spans`).  ``t0``/``t1`` are
+    ``perf_counter`` seconds; ``set`` adds arguments once the span is
+    open (a uid known only inside it)."""
+
+    __slots__ = ("_owner", "name", "args", "stage", "_ann", "t0", "t1")
+
+    def __init__(self, owner: "Spans", name: str, args: dict, stage: bool):
+        self._owner, self.name, self.args, self.stage = (owner, name, args,
+                                                         stage)
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = _CLOCK()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = _CLOCK()
+        self._ann.__exit__(*exc)
+        self._owner._close(self)
+
+    def set(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self.args.update(args)
+
+
+class Spans:
+    """Host spans of one thread's work, named ``dllm.<what>``.
+
+    ``span(name, **args)`` marks work; ``stage(name)`` marks a tick stage
+    and also adds its seconds to ``seconds[<what>]`` (the name without the
+    ``dllm.`` prefix), which :meth:`take` hands over once per tick.  With
+    an enabled collector each span becomes one complete event there, named
+    without the prefix.  Not thread-safe: one instance per thread that
+    writes ``seconds``."""
+
+    def __init__(self, trace: Optional["TraceCollector"] = None,
+                 cat: str = ""):
+        self.trace = trace
+        self.cat = cat
+        self.seconds: Dict[str, float] = {}
+
+    def span(self, name: str, **args) -> Span:
+        return Span(self, name, args, False)
+
+    def stage(self, name: str, **args) -> Span:
+        return Span(self, name, args, True)
+
+    def take(self) -> Dict[str, float]:
+        """The stage seconds summed since the last call, and a fresh
+        sum."""
+        out, self.seconds = self.seconds, {}
+        return out
+
+    def _close(self, sp: Span) -> None:
+        short = sp.name[len(PREFIX):] if sp.name.startswith(PREFIX) \
+            else sp.name
+        dur = sp.t1 - sp.t0
+        if sp.stage:
+            self.seconds[short] = self.seconds.get(short, 0.0) + dur
+        tr = self.trace
+        if tr is not None and tr.enabled:
+            tr.complete(short, self.cat, sp.t0 * 1e6, dur * 1e6,
+                        args=sp.args or None)
 
 
 class TraceCollector:

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import diffusion, schedule as schedule_lib
+from repro.obs.tracing import Spans
 from repro.serving.cache_pool import CachePool, PagedCachePool, SpilledSlot
 from repro.serving.metrics import MetricsTracker
 from repro.serving.scheduler import (FIFOPolicy, Policy, SlowFastPolicy,
@@ -131,6 +132,9 @@ class CommitEvent:
     masks_left: int               # masks left in the active block after tick
     done: bool = False
     final_tokens: Optional[np.ndarray] = None   # (P + gen,) when done
+    # perf_counter at creation on the engine thread: the frontend's
+    # dllm_delivery_seconds measures from here to the SSE write
+    created: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 @dataclasses.dataclass
@@ -230,6 +234,10 @@ class ServingEngine:
         # costs nothing on the hot path without events.
         self._event = obs.event if obs is not None \
             and hasattr(obs, "event") else None
+        # dllm.* host spans of this engine's thread (tick stages and the
+        # frontend worker loop): profiler annotations, the stage seconds
+        # obs histograms, and --trace-out events (docs/observability.md)
+        self.spans = Spans(getattr(obs, "trace", None), cat="engine")
         self._early_exits_seen = 0
         self.fwd_kw = dict(fwd_kw or {})
         # QuantPolicy is not a jax type: bind it statically into the jitted
@@ -759,6 +767,96 @@ class ServingEngine:
             jax.block_until_ready(out)
         return self
 
+    def _admit_ready(self) -> bool:
+        """Admit what has arrived (fast-forwarding the virtual clock to
+        the next arrival when no slot is active) and upload the validity
+        mask.  False when there is nothing left to run."""
+        self._admit()
+        if self.active_slots == 0:
+            nxt = self._next_arrival()
+            if nxt is None:
+                return False
+            self.now = max(self.now, nxt)     # fast-forward through idle gap
+            self._admit()
+        self._flush_kv_valid()
+        return True
+
+    def _advance(self, i: int, s: _Slot, cb, masks_left: int, conf: float,
+                 positions, tokens, rows: Optional[np.ndarray]) -> None:
+        """One tick of slot ``i``'s host state machine once the device has
+        reported its ``masks_left`` and ``conf``: first commit, event-log
+        record, block boundary (releasing the request with its row of
+        ``rows`` when it is done) or next step, and the commit callback.
+        ``positions``/``tokens`` are the streaming diff (None without a
+        callback)."""
+        obs = self.obs
+        uid = s.request.uid
+        L = self.dcfg.block_length
+        if not s.first_commit and masks_left < L:
+            s.first_commit = True
+            s.first_commit_t = self.now
+            self.metrics.request_first_commit(uid, self.now)
+            if obs is not None:
+                obs.request_first_commit(
+                    uid, max(0.0, self.now - s.request.arrival_time))
+        block_idx, step_in_block = s.block_idx, s.step_in_block
+        # event-log commit record precedes any done record _release
+        # emits this tick (lifecycle order: block_commit, then done)
+        self._emit_commit(s.request, cb, self.ticks_total, block_idx,
+                          step_in_block, positions, tokens, masks_left,
+                          s.block_masks_left)
+        done = False
+        final: Optional[np.ndarray] = None
+        if masks_left == 0:                   # block fully committed
+            if obs is not None:
+                obs.block_committed(
+                    uid, block_idx, self.ticks_total,
+                    len(positions) if positions is not None
+                    else s.block_masks_left,
+                    positions, tokens)
+            s.block_idx += 1
+            s.step_in_block = 0
+            s.last_conf = float("-inf")
+            s.block_masks_left = L
+            if s.block_idx * L >= s.request.gen_length:
+                done = True
+                if cb is not None:
+                    final = rows[i, :s.request.total_len].copy()
+                self._release(i, rows[i])
+        else:
+            s.step_in_block += 1
+            s.last_conf = conf
+            s.block_masks_left = masks_left
+        if cb is not None:
+            cb(CommitEvent(
+                uid=uid, tick=self.ticks_total, now=self.now,
+                block_idx=block_idx, step_in_block=step_in_block,
+                positions=positions, tokens=tokens,
+                masks_left=masks_left, done=done, final_tokens=final))
+            if done:
+                del self._commit_cbs[uid]
+
+    def _tick_obs(self, stages: Dict[str, float], committed: int,
+                  ticks: List[Tuple[float, float, int]]) -> None:
+        """Per-call obs: tokens, early exits, pool gauges, and one
+        ``obs.tick`` per denoising tick ``(start_s, seconds, active)``
+        with its share of the call's stage seconds."""
+        obs = self.obs
+        obs.tokens_committed(committed)
+        ee = self._early_exits_total()
+        if ee > self._early_exits_seen:
+            obs.policy_early_exit(ee - self._early_exits_seen)
+            if self._event is not None:
+                self._event("early_exit", t=self.now,
+                            n=ee - self._early_exits_seen)
+            self._early_exits_seen = ee
+        if self.paged:
+            obs.pool_pages(self.pool)
+        per_tick = {name: s_sec / len(ticks) for name, s_sec in stages.items()}
+        queued = len(self.queue)
+        for t0, dt, active in ticks:
+            obs.tick(per_tick, dt, active, queued, t_start_us=t0 * 1e6)
+
     def tick(self, max_ticks: Optional[int] = None) -> bool:
         """Admit, run one fused batched step, advance slot states.
 
@@ -771,97 +869,78 @@ class ServingEngine:
         ``ticks_total``, which counts denoising ticks in both modes."""
         if self.megatick_k > 1:
             return self._megastep(max_ticks)
-        obs = self.obs
-        t_enter = time.perf_counter()
-        self._admit()
-        if self.active_slots == 0:
-            nxt = self._next_arrival()
-            if nxt is None:
-                return False
-            self.now = max(self.now, nxt)     # fast-forward through idle gap
-            self._admit()
-        self._flush_kv_valid()
-        paged_io = 0.0
-        if self.paged:
-            # staged canvas uploads + dirty tables; timed as its own
-            # stage so the drift monitor can compare measured paged
-            # gather/scatter overhead against the analytical page_io term
-            tp0 = time.perf_counter()
-            self.pool.flush()
-            paged_io = time.perf_counter() - tp0
-
-        T = self.dcfg.steps_per_block
+        sp = self.spans
         L = self.dcfg.block_length
-        bs_np = np.zeros((self.num_slots,), np.int32)
-        k_np = np.zeros((self.num_slots,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            bs_np[i] = s.request.prompt_len + s.block_idx * L
-            t = s.step_in_block
-            default_k = int(self._ksched[t]) if t < T else s.block_masks_left
-            pol = s.policy or self.policy
-            k_np[i] = min(pol.step_k(s, default_k), L)
-
-        # per-stage tick timing (docs/observability.md): host_prep is the
-        # pure-python admission + k-schedule bookkeeping; everything that
-        # talks to the runtime — the eager rng split (an XLA computation
-        # of its own), the bs/k host->device puts, and the tick call —
-        # is *dispatch*, and device_sync is the wait on results.  That
-        # dispatch/device_sync pair is exactly the per-tick host tax the
-        # megatick path amortizes over K ticks (docs/megatick.md); with
-        # ``breakdown`` the dispatch window instead splits into blocking
-        # forward / sampling stages.  Costs a handful of perf_counter
-        # reads; stage values only leave the tick via ``obs``/breakdown
-        # metrics.
-        stages: Dict[str, float] = {}
-        t0 = time.perf_counter()
-        stages["host_prep"] = t0 - t_enter - paged_io
+        T = self.dcfg.steps_per_block
+        # tick stages (docs/observability.md), each a dllm.* span: host_prep
+        # is admission and the k schedule; everything that talks to the
+        # runtime — the eager rng split (an XLA computation of its own),
+        # the bs/k host->device puts, and the tick call — is dispatch, and
+        # device_sync is the wait on results.  That dispatch/device_sync
+        # pair is the per-tick host tax the megatick amortizes over K
+        # ticks (docs/megatick.md); with ``breakdown`` the dispatch window
+        # instead splits into blocking forward / sampling stages.
+        with sp.stage("dllm.host_prep") as prep:
+            with sp.span("dllm.admit"):
+                ready = self._admit_ready()
+            bs_np = np.zeros((self.num_slots,), np.int32)
+            k_np = np.zeros((self.num_slots,), np.int32)
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                bs_np[i] = s.request.prompt_len + s.block_idx * L
+                t = s.step_in_block
+                default_k = (int(self._ksched[t]) if t < T
+                             else s.block_masks_left)
+                pol = s.policy or self.policy
+                k_np[i] = min(pol.step_k(s, default_k), L)
+        if not ready:
+            sp.take()                         # an idle call is no tick
+            return False
         if self.paged:
-            stages["paged_io"] = paged_io
-        bs_vec = jnp.asarray(bs_np)
-        k_vec = jnp.asarray(k_np)
-        self.rng, srng = jax.random.split(self.rng)
+            # staged canvas uploads + dirty tables: a stage of its own, so
+            # the drift monitor can compare the measured paged overhead
+            # against the analytical page_io term
+            with sp.stage("dllm.paged_io"):
+                self.pool.flush()
         cache = self.pool.cache if self.mode == "warm" else None
-        if self.paged:
-            # one fused gather -> tick -> scatter call; x_new is the dense
-            # post-tick canvas view (the same array the slot tick returns),
-            # so streaming diffs and release reads are unchanged
-            canvas, new_cache, x_new, conf_min, masks_left = self._tick_fn(
-                self.params, self.pool.canvas_pages, cache,
-                self.pool.canvas_table, self.pool.kv_table, self.kv_valid,
-                bs_vec, k_vec, srng)
-            self.pool.canvas_pages = canvas
-            t2 = time.perf_counter()
-            stages["dispatch"] = t2 - t0
-        elif self.breakdown:
-            feats, new_cache = self._fwd_fn(
-                self.params, self.x, self.kv_valid, bs_vec, cache,
-                **self.fwd_kw)
-            jax.block_until_ready(feats)
-            t1 = time.perf_counter()
-            self.metrics.record_stage("forward", t1 - t0)
-            stages["forward"] = t1 - t0
+        with sp.stage("dllm.forward" if self.breakdown
+                      else "dllm.dispatch") as first:
+            bs_vec = jnp.asarray(bs_np)
+            k_vec = jnp.asarray(k_np)
+            self.rng, srng = jax.random.split(self.rng)
+            if self.breakdown:
+                feats, new_cache = self._fwd_fn(
+                    self.params, self.x, self.kv_valid, bs_vec, cache,
+                    **self.fwd_kw)
+                jax.block_until_ready(feats)
+            elif self.paged:
+                # one fused gather -> tick -> scatter call; x_new is the
+                # dense post-tick canvas view (the same array the slot tick
+                # returns), so streaming diffs and release reads are
+                # unchanged
+                canvas, new_cache, x_new, conf_min, masks_left = \
+                    self._tick_fn(self.params, self.pool.canvas_pages, cache,
+                                  self.pool.canvas_table, self.pool.kv_table,
+                                  self.kv_valid, bs_vec, k_vec, srng)
+                self.pool.canvas_pages = canvas
+            else:
+                x_new, new_cache, conf_min, masks_left = self._tick_fn(
+                    self.params, self.x, self.kv_valid, bs_vec, k_vec, srng,
+                    cache, **self.fwd_kw)
+        if self.breakdown:
             # feats = pre-head hidden states for head-capable models: the
             # sampling stage owns the LM head (the paper's Fig. 1 split
             # charges vocab traffic to sampling, not the model forward)
-            x_new, conf_min, masks_left = self._smp_fn(
-                self.params, feats, self.x, bs_vec, k_vec, srng)
-            jax.block_until_ready(x_new)
-            t2 = time.perf_counter()
-            self.metrics.record_stage("sampling", t2 - t1)
-            stages["sampling"] = t2 - t1
-        else:
-            x_new, new_cache, conf_min, masks_left = self._tick_fn(
-                self.params, self.x, self.kv_valid, bs_vec, k_vec, srng,
-                cache, **self.fwd_kw)
-            t2 = time.perf_counter()
-            stages["dispatch"] = t2 - t0
-        conf_np = np.asarray(conf_min)        # device sync point
-        masks_np = np.asarray(masks_left)
-        t3 = time.perf_counter()
-        stages["host_sync" if self.breakdown else "device_sync"] = t3 - t2
-        dt = t3 - t0
+            with sp.stage("dllm.sampling"):
+                x_new, conf_min, masks_left = self._smp_fn(
+                    self.params, feats, self.x, bs_vec, k_vec, srng)
+                jax.block_until_ready(x_new)
+        with sp.stage("dllm.host_sync" if self.breakdown
+                      else "dllm.device_sync") as synced:
+            conf_np = np.asarray(conf_min)    # device sync point
+            masks_np = np.asarray(masks_left)
+        dt = synced.t1 - first.t0
         self.x = x_new
         if self.mode == "warm":
             self.pool.update(new_cache)
@@ -870,101 +949,49 @@ class ServingEngine:
         self.now += dt
         self.ticks_total += 1
         self.metrics.record_tick(dt, n_active)
-        t4 = time.perf_counter()
-        committed_total = 0
-        x_host: Optional[np.ndarray] = None
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            s.ticks += 1
-            uid = s.request.uid
-            cb = self._commit_cbs.get(uid)
-            masks_left = int(masks_np[i])
-            committed_total += max(0, s.block_masks_left - masks_left)
-            # host copy only when someone will read it: a streaming diff,
-            # or a request completing this tick (release needs the row);
-            # intermediate block boundaries without callbacks stay on
-            # device, matching the pre-streaming sync behavior
-            if x_host is None and (cb is not None or (
-                    masks_left == 0
-                    and (s.block_idx + 1) * L >= s.request.gen_length)):
-                x_host = np.asarray(self.x)   # one host copy serves all rows
-            positions = tokens = None
-            if cb is not None:
-                # streaming diff: what unmasked on this tick, against the
-                # host-tracked mask mirror (no extra device sync — x_host
-                # is the copy the release path fetches anyway)
-                row = x_host[i, :s.request.total_len]
-                newly = s.masked & (row != self.mask_id)
-                positions = np.nonzero(newly)[0]
-                tokens = row[positions].copy()
-                s.masked &= ~newly
-            if not s.first_commit and masks_left < L:
-                s.first_commit = True
-                s.first_commit_t = self.now
-                self.metrics.request_first_commit(uid, self.now)
-                if obs is not None:
-                    obs.request_first_commit(
-                        uid, max(0.0, self.now - s.request.arrival_time))
-            block_idx, step_in_block = s.block_idx, s.step_in_block
-            # event-log commit record precedes any done record _release
-            # emits this tick (lifecycle order: block_commit, then done)
-            self._emit_commit(s.request, cb, self.ticks_total, block_idx,
-                              step_in_block, positions, tokens, masks_left,
-                              s.block_masks_left)
-            done = False
-            final: Optional[np.ndarray] = None
-            if masks_left == 0:               # block fully committed
-                if obs is not None:
-                    obs.block_committed(
-                        uid, block_idx, self.ticks_total,
-                        len(positions) if positions is not None
-                        else s.block_masks_left,
-                        positions, tokens)
-                s.block_idx += 1
-                s.step_in_block = 0
-                s.last_conf = float("-inf")
-                s.block_masks_left = L
-                if s.block_idx * L >= s.request.gen_length:
-                    done = True
-                    if cb is not None:
-                        final = x_host[i, :s.request.total_len].copy()
-                    self._release(i, x_host[i])
-            else:
-                s.step_in_block += 1
-                s.last_conf = float(conf_np[i])
-                s.block_masks_left = masks_left
-            if cb is not None:
-                cb(CommitEvent(
-                    uid=uid, tick=self.ticks_total, now=self.now,
-                    block_idx=block_idx, step_in_block=step_in_block,
-                    positions=positions, tokens=tokens,
-                    masks_left=masks_left, done=done, final_tokens=final))
-                if done:
-                    del self._commit_cbs[uid]
-        if x_host is None and n_active:
-            # no streaming sink and no release needed the canvas this
-            # tick: the mask-mirror-diff host fetch was skipped entirely
-            self.host_syncs_elided += 1
-            if obs is not None:
-                obs.host_syncs_elided(1)
-        stages["commit"] = time.perf_counter() - t4
+        with sp.stage("dllm.commit"):
+            committed_total = 0
+            x_host: Optional[np.ndarray] = None
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                s.ticks += 1
+                cb = self._commit_cbs.get(s.request.uid)
+                masks_left = int(masks_np[i])
+                committed_total += max(0, s.block_masks_left - masks_left)
+                # host copy only when someone will read it: a streaming
+                # diff, or a request completing this tick (release needs the
+                # row); intermediate block boundaries without callbacks stay
+                # on device, matching the pre-streaming sync behavior
+                if x_host is None and (cb is not None or (
+                        masks_left == 0
+                        and (s.block_idx + 1) * L >= s.request.gen_length)):
+                    with sp.span("dllm.canvas_fetch"):
+                        x_host = np.asarray(self.x)   # one copy, all rows
+                positions = tokens = None
+                if cb is not None:
+                    # streaming diff: what unmasked on this tick, against
+                    # the host-tracked mask mirror (no extra device sync —
+                    # x_host is the copy the release path fetches anyway)
+                    row = x_host[i, :s.request.total_len]
+                    newly = s.masked & (row != self.mask_id)
+                    positions = np.nonzero(newly)[0]
+                    tokens = row[positions].copy()
+                    s.masked &= ~newly
+                self._advance(i, s, cb, masks_left, float(conf_np[i]),
+                              positions, tokens, x_host)
+            if x_host is None and n_active:
+                # no streaming sink and no release needed the canvas this
+                # tick: the mask-mirror-diff host fetch was skipped entirely
+                self.host_syncs_elided += 1
+                if self.obs is not None:
+                    self.obs.host_syncs_elided(1)
+        stages = sp.take()
         for name, s_sec in stages.items():
-            if name not in ("forward", "sampling"):   # recorded in-branch
-                self.metrics.record_stage(name, s_sec)
-        if obs is not None:
-            obs.tokens_committed(committed_total)
-            ee = self._early_exits_total()
-            if ee > self._early_exits_seen:
-                obs.policy_early_exit(ee - self._early_exits_seen)
-                if self._event is not None:
-                    self._event("early_exit", t=self.now,
-                                n=ee - self._early_exits_seen)
-                self._early_exits_seen = ee
-            if self.paged:
-                obs.pool_pages(self.pool)
-            obs.tick(stages, dt, self.active_slots, len(self.queue),
-                     t_start_us=t_enter * 1e6)
+            self.metrics.record_stage(name, s_sec)
+        if self.obs is not None:
+            self._tick_obs(stages, committed_total,
+                           [(prep.t0, dt, self.active_slots)])
         return True
 
     # -- device-resident megatick (docs/megatick.md) ------------------------
@@ -994,84 +1021,73 @@ class ServingEngine:
         machine — metrics, streaming callbacks, and obs hooks see the
         identical per-tick event sequence the K=1 path produces, with
         contiguous tick numbering and one device sync per megastep
-        instead of per tick."""
-        obs = self.obs
-        t_enter = time.perf_counter()
-        self._admit()
-        if self.active_slots == 0:
-            nxt = self._next_arrival()
-            if nxt is None:
-                return False
-            self.now = max(self.now, nxt)     # fast-forward through idle gap
-            self._admit()
-        self._flush_kv_valid()
-        paged_io = 0.0
-        if self.paged:
-            # tables are constant across the megastep; timed as its own
-            # stage (per-tick share = paged_io / n, like dispatch)
-            tp0 = time.perf_counter()
-            self.pool.flush()
-            paged_io = time.perf_counter() - tp0
-        k_req, stop_on_release = self._choose_megatick_k(max_ticks)
-
+        instead of per tick.  The stages are the K=1 tick's; each
+        replayed tick is a ``dllm.replay`` span inside ``dllm.commit``."""
+        sp = self.spans
         L = self.dcfg.block_length
         B = self.num_slots
-        pl = np.zeros((B,), np.int32)
-        gb = np.zeros((B,), np.int32)
-        bi = np.zeros((B,), np.int32)
-        ti = np.zeros((B,), np.int32)
-        bml = np.zeros((B,), np.int32)
-        lc = np.full((B,), -np.inf, np.float32)
-        act = np.zeros((B,), bool)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            pl[i] = s.request.prompt_len
-            gb[i] = s.request.gen_length // L
-            bi[i] = s.block_idx
-            ti[i] = s.step_in_block
-            bml[i] = s.block_masks_left
-            lc[i] = s.last_conf
-            act[i] = True
-        cache = self.pool.cache if self.mode == "warm" else None
-
-        stages: Dict[str, float] = {}
-        t0 = time.perf_counter()
-        stages["host_prep"] = t0 - t_enter - paged_io
+        with sp.stage("dllm.host_prep") as prep:
+            with sp.span("dllm.admit"):
+                ready = self._admit_ready()
+            k_req, stop_on_release = self._choose_megatick_k(max_ticks)
+            pl = np.zeros((B,), np.int32)
+            gb = np.zeros((B,), np.int32)
+            bi = np.zeros((B,), np.int32)
+            ti = np.zeros((B,), np.int32)
+            bml = np.zeros((B,), np.int32)
+            lc = np.full((B,), -np.inf, np.float32)
+            act = np.zeros((B,), bool)
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                pl[i] = s.request.prompt_len
+                gb[i] = s.request.gen_length // L
+                bi[i] = s.block_idx
+                ti[i] = s.step_in_block
+                bml[i] = s.block_masks_left
+                lc[i] = s.last_conf
+                act[i] = True
+        if not ready:
+            sp.take()                         # an idle call is no tick
+            return False
         if self.paged:
-            stages["paged_io"] = paged_io
+            # tables are constant across the megastep; a stage of its own
+            # (per-tick share = paged_io / n, like dispatch)
+            with sp.stage("dllm.paged_io"):
+                self.pool.flush()
+        cache = self.pool.cache if self.mode == "warm" else None
         # dispatch window mirrors the K=1 path: the state host->device
         # puts plus the single fused call.  x and cache are *donated*
         # into the loop (the engine rebinds both from the outputs below)
-        state = diffusion.megatick_state(
-            pl, gb, self.dcfg, block_idx=bi, step_in_block=ti,
-            block_masks_left=bml, last_conf=lc, active=act)
-        if self.paged:
-            # page stores are donated into the fused loop; rebind both
-            canvas, new_cache, x_new, rng_new, _, bufs, n_dev = \
-                self._megatick_fn(
-                    self.params, self.pool.canvas_pages, cache,
-                    self.pool.canvas_table, self.pool.kv_table,
-                    self.kv_valid, state, self.rng, jnp.int32(k_req),
-                    jnp.asarray(bool(stop_on_release)))
-            self.pool.canvas_pages = canvas
-        else:
-            x_new, new_cache, rng_new, _, bufs, n_dev = self._megatick_fn(
-                self.params, self.x, self.kv_valid, state, self.rng,
-                jnp.int32(k_req), jnp.asarray(bool(stop_on_release)), cache)
-        t2 = time.perf_counter()
-        stages["dispatch"] = t2 - t0
-        n = int(n_dev)                        # THE device sync point
-        masks_b = np.asarray(bufs["masks_left"])
-        conf_b = np.asarray(bufs["conf"])
-        early_b = (np.asarray(bufs["early"])
-                   if self._sf_threshold is not None else None)
-        sinks = any(s is not None and s.request.uid in self._commit_cbs
-                    for s in self.slots)
-        xa_b = np.asarray(bufs["xa"]) if sinks else None
-        t3 = time.perf_counter()
-        stages["device_sync"] = t3 - t2
-        dt = t3 - t0
+        with sp.stage("dllm.dispatch") as first:
+            state = diffusion.megatick_state(
+                pl, gb, self.dcfg, block_idx=bi, step_in_block=ti,
+                block_masks_left=bml, last_conf=lc, active=act)
+            if self.paged:
+                # page stores are donated into the fused loop; rebind both
+                canvas, new_cache, x_new, rng_new, _, bufs, n_dev = \
+                    self._megatick_fn(
+                        self.params, self.pool.canvas_pages, cache,
+                        self.pool.canvas_table, self.pool.kv_table,
+                        self.kv_valid, state, self.rng, jnp.int32(k_req),
+                        jnp.asarray(bool(stop_on_release)))
+                self.pool.canvas_pages = canvas
+            else:
+                x_new, new_cache, rng_new, _, bufs, n_dev = \
+                    self._megatick_fn(
+                        self.params, self.x, self.kv_valid, state, self.rng,
+                        jnp.int32(k_req), jnp.asarray(bool(stop_on_release)),
+                        cache)
+        with sp.stage("dllm.device_sync") as synced:
+            n = int(n_dev)                    # THE device sync point
+            masks_b = np.asarray(bufs["masks_left"])
+            conf_b = np.asarray(bufs["conf"])
+            early_b = (np.asarray(bufs["early"])
+                       if self._sf_threshold is not None else None)
+            sinks = any(s is not None and s.request.uid in self._commit_cbs
+                        for s in self.slots)
+            xa_b = np.asarray(bufs["xa"]) if sinks else None
+        dt = synced.t1 - first.t0
         self.x = x_new
         self.rng = rng_new
         if self.mode == "warm":
@@ -1079,109 +1095,62 @@ class ServingEngine:
         elided = (n - 1) + (0 if sinks else 1)
         if elided > 0:
             self.host_syncs_elided += elided
-            if obs is not None:
-                obs.host_syncs_elided(elided)
+            if self.obs is not None:
+                self.obs.host_syncs_elided(elided)
 
-        t4 = time.perf_counter()
         now0 = self.now
         committed_total = 0
         x_final: Optional[np.ndarray] = None
-        active_counts: List[int] = []
-        for j in range(n):
-            self.now = now0 + dt * (j + 1) / n
-            self.ticks_total += 1
-            active_counts.append(self.active_slots)
-            self.metrics.record_tick(dt / n, self.active_slots)
-            for i, s in enumerate(self.slots):
-                if s is None:
-                    continue
-                s.ticks += 1
-                uid = s.request.uid
-                cb = self._commit_cbs.get(uid)
-                masks_left = int(masks_b[j, i])
-                committed_total += max(0, s.block_masks_left - masks_left)
-                positions = tokens = None
-                if cb is not None:
-                    bs = s.request.prompt_len + s.block_idx * L
-                    xa = xa_b[j, i]
-                    newly = s.masked[bs:bs + L] & (xa != self.mask_id)
-                    local = np.nonzero(newly)[0]
-                    positions = bs + local
-                    tokens = xa[local].copy()
-                    s.masked[bs:bs + L] &= ~newly
-                if not s.first_commit and masks_left < L:
-                    s.first_commit = True
-                    s.first_commit_t = self.now
-                    self.metrics.request_first_commit(uid, self.now)
-                    if obs is not None:
-                        obs.request_first_commit(
-                            uid, max(0.0, self.now - s.request.arrival_time))
-                block_idx, step_in_block = s.block_idx, s.step_in_block
-                self._emit_commit(s.request, cb, self.ticks_total,
-                                  block_idx, step_in_block, positions,
-                                  tokens, masks_left, s.block_masks_left)
-                done = False
-                final: Optional[np.ndarray] = None
-                if masks_left == 0:           # block fully committed
-                    if obs is not None:
-                        obs.block_committed(
-                            uid, block_idx, self.ticks_total,
-                            len(positions) if positions is not None
-                            else s.block_masks_left,
-                            positions, tokens)
-                    s.block_idx += 1
-                    s.step_in_block = 0
-                    s.last_conf = float("-inf")
-                    s.block_masks_left = L
-                    if s.block_idx * L >= s.request.gen_length:
-                        done = True
-                        if x_final is None:
+        ticks: List[Tuple[float, float, int]] = []
+        with sp.stage("dllm.commit"):
+            for j in range(n):
+                with sp.span("dllm.replay"):
+                    self.now = now0 + dt * (j + 1) / n
+                    self.ticks_total += 1
+                    ticks.append((prep.t0 + j * (dt / n), dt / n,
+                                  self.active_slots))
+                    self.metrics.record_tick(dt / n, self.active_slots)
+                    for i, s in enumerate(self.slots):
+                        if s is None:
+                            continue
+                        s.ticks += 1
+                        cb = self._commit_cbs.get(s.request.uid)
+                        masks_left = int(masks_b[j, i])
+                        committed_total += max(
+                            0, s.block_masks_left - masks_left)
+                        positions = tokens = None
+                        if cb is not None:
+                            bs = s.request.prompt_len + s.block_idx * L
+                            xa = xa_b[j, i]
+                            newly = (s.masked[bs:bs + L]
+                                     & (xa != self.mask_id))
+                            local = np.nonzero(newly)[0]
+                            positions = bs + local
+                            tokens = xa[local].copy()
+                            s.masked[bs:bs + L] &= ~newly
+                        if x_final is None and masks_left == 0 and (
+                                (s.block_idx + 1) * L
+                                >= s.request.gen_length):
                             # released rows tick with k=0 afterwards, so
                             # the final canvas still holds their rows
-                            x_final = np.asarray(self.x)
-                        if cb is not None:
-                            final = x_final[i, :s.request.total_len].copy()
-                        self._release(i, x_final[i])
-                else:
-                    s.step_in_block += 1
-                    s.last_conf = float(conf_b[j, i])
-                    s.block_masks_left = masks_left
-                if cb is not None:
-                    cb(CommitEvent(
-                        uid=uid, tick=self.ticks_total, now=self.now,
-                        block_idx=block_idx, step_in_block=step_in_block,
-                        positions=positions, tokens=tokens,
-                        masks_left=masks_left, done=done,
-                        final_tokens=final))
-                    if done:
-                        del self._commit_cbs[uid]
-        if early_b is not None:
-            self.policy.early_exits += int(early_b[:n].sum())
-        stages["commit"] = time.perf_counter() - t4
+                            with sp.span("dllm.canvas_fetch"):
+                                x_final = np.asarray(self.x)
+                        self._advance(i, s, cb, masks_left,
+                                      float(conf_b[j, i]), positions,
+                                      tokens, x_final)
+            if early_b is not None:
+                self.policy.early_exits += int(early_b[:n].sum())
+        stages = sp.take()
         for name, s_sec in stages.items():
             self.metrics.record_stage(name, s_sec)
-        if obs is not None:
-            obs.tokens_committed(committed_total)
-            ee = self._early_exits_total()
-            if ee > self._early_exits_seen:
-                obs.policy_early_exit(ee - self._early_exits_seen)
-                if self._event is not None:
-                    self._event("early_exit", t=self.now,
-                                n=ee - self._early_exits_seen)
-                self._early_exits_seen = ee
-            if self.paged:
-                obs.pool_pages(self.pool)
+        if self.obs is not None:
             # per-megastep stages with per-tick attribution: every
             # replayed tick carries 1/n of the megastep's stage seconds,
             # so the dispatch/device_sync histograms directly show the
             # amortization (and the drift monitor compares against
             # host_overhead_per_tick(host, K))
-            per_tick = {name: s_sec / n for name, s_sec in stages.items()}
-            queued = len(self.queue)
-            for j in range(n):
-                obs.tick(per_tick, dt / n, active_counts[j], queued,
-                         t_start_us=(t_enter + j * (dt / n)) * 1e6)
-            obs.megastep(n, k_req, dt, t_start_us=t_enter * 1e6)
+            self._tick_obs(stages, committed_total, ticks)
+            self.obs.megastep(n, k_req, dt, t_start_us=prep.t0 * 1e6)
         return True
 
     def run(self, requests: Optional[Sequence[Request]] = None

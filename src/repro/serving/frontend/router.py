@@ -316,21 +316,31 @@ class EngineWorker:
             raise
 
     def _loop_inner(self) -> None:
+        # every instant of this thread lies under a dllm.* span:
+        # dllm.engine_tick around a tick() call (the tick's stage spans
+        # nest inside it; it alone covers the obs bookkeeping after the
+        # commit stage), and around it dllm.submit (staged requests into
+        # the engine, deadline sheds), dllm.worker_sync (load snapshots,
+        # metrics compaction) and dllm.worker_wait
         eng = self.engine
+        sp = eng.spans
         while True:
-            with self._lock:
-                staged, self._staging = self._staging, []
-            for req, deliver in staged:
-                try:
-                    eng.submit(req, on_commit=functools.partial(
-                        self._on_commit, deliver))
-                    self._sinks[req.uid] = deliver
-                except ValueError as e:
-                    # the server validates before staging; this is the
-                    # belt-and-braces path (e.g. duplicate uid)
-                    deliver(ShedEvent(uid=req.uid,
-                                      reason=f"rejected: {e}"))
-            self._shed_expired(eng)
+            with sp.span("dllm.submit") as span:
+                with self._lock:
+                    staged, self._staging = self._staging, []
+                for req, deliver in staged:
+                    try:
+                        eng.submit(req, on_commit=functools.partial(
+                            self._on_commit, deliver))
+                        self._sinks[req.uid] = deliver
+                    except ValueError as e:
+                        # the server validates before staging; this is the
+                        # belt-and-braces path (e.g. duplicate uid)
+                        deliver(ShedEvent(uid=req.uid,
+                                          reason=f"rejected: {e}"))
+                self._shed_expired(eng)
+                if staged:
+                    span.set(requests=len(staged))
             if eng.pending:
                 # online serving runs on the wall clock: sync the engine's
                 # virtual `now` up to real time before the tick, or queued
@@ -345,11 +355,10 @@ class EngineWorker:
                 # --profile-ticks N captures exactly N ticks at any K —
                 # while profiling, cap the megastep at the remaining budget
                 prev_ticks = eng.ticks_total
-                if self._profiling:
-                    progressed = eng.tick(
-                        max_ticks=max(1, self.profile_ticks - self._profiled))
-                else:
-                    progressed = eng.tick()
+                cap = (max(1, self.profile_ticks - self._profiled)
+                       if self._profiling else None)
+                with sp.span("dllm.engine_tick"):
+                    progressed = eng.tick(max_ticks=cap)
                 n_ticks = eng.ticks_total - prev_ticks
                 if self._profiling:
                     self._profiled += n_ticks
@@ -360,27 +369,12 @@ class EngineWorker:
                     rem = (self.tick_floor_s * max(1, n_ticks)
                            - (time.perf_counter() - t_tick))
                     if rem > 0:
-                        time.sleep(rem)       # emulated device wait
+                        with sp.span("dllm.worker_wait"):
+                            time.sleep(rem)   # emulated device wait
             else:
                 progressed = False
-            with self._lock:
-                self.queued = len(eng.queue) + len(self._staging)
-                if self.paged:
-                    self.queued_pages = (
-                        sum(self._pages_of(r) for r in eng.queue)
-                        + sum(self._pages_of(r) for r, _ in self._staging))
-            self.active = eng.active_slots
-            self.free_slots = eng.pool.free_slots
-            if self.paged:
-                self.free_pages = self._free_pages_snapshot()
-            # results already reached clients through the commit callbacks;
-            # nothing reads eng.completed in server mode, so drain it (and
-            # periodically fold old metrics records into aggregates) or a
-            # long-lived replica grows per-request state without bound
-            if eng.completed:
-                self.completed += len(eng.completed)
-                eng.completed.clear()
-                eng.metrics.compact()
+            with sp.span("dllm.worker_sync"):
+                self._sync(eng)
             if self._stop:
                 if self._abort:
                     # shed *everything* still pending, including requests
@@ -405,10 +399,33 @@ class EngineWorker:
                 with self._lock:
                     idle = not self._staging and not self._stop
                 if idle:
-                    self._wake.wait(timeout=0.1)
+                    with sp.span("dllm.worker_wait"):
+                        self._wake.wait(timeout=0.1)
                 self._wake.clear()
         self._profile_stop_if_done(force=True)
         eng.metrics.elapsed = eng.now
+
+    def _sync(self, eng: ServingEngine) -> None:
+        """Refresh the load snapshots the event loop reads, and drain
+        finished requests."""
+        with self._lock:
+            self.queued = len(eng.queue) + len(self._staging)
+            if self.paged:
+                self.queued_pages = (
+                    sum(self._pages_of(r) for r in eng.queue)
+                    + sum(self._pages_of(r) for r, _ in self._staging))
+        self.active = eng.active_slots
+        self.free_slots = eng.pool.free_slots
+        if self.paged:
+            self.free_pages = self._free_pages_snapshot()
+        # results already reached clients through the commit callbacks;
+        # nothing reads eng.completed in server mode, so drain it (and
+        # periodically fold old metrics records into aggregates) or a
+        # long-lived replica grows per-request state without bound
+        if eng.completed:
+            self.completed += len(eng.completed)
+            eng.completed.clear()
+            eng.metrics.compact()
 
 
 class Router:

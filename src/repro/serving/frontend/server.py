@@ -33,7 +33,8 @@ import time
 from typing import Optional, Set
 
 from repro.obs import CONTENT_TYPE as _METRICS_CT
-from repro.obs import ServingObs, frontend_metrics
+from repro.obs import (ServingObs, Spans, delivery_histogram,
+                       frontend_metrics)
 from repro.obs.registry import OPENMETRICS_CONTENT_TYPE as _OM_CT
 from repro.serving.engine import CommitEvent, Request
 from repro.serving.frontend import protocol
@@ -71,6 +72,12 @@ class ServeFrontend:
         self.slo_classes = getattr(obs, "slo_classes", None)
         self._http, self._submits, self._overloaded = frontend_metrics(
             obs.registry)
+        # dllm.* spans of the event-loop thread, and per replica the
+        # commit -> SSE write delivery histogram
+        self._spans = Spans(obs.trace, cat="frontend")
+        delivery = delivery_histogram(obs.registry)
+        self._delivery = {w.name: delivery.labels(replica=w.name)
+                          for w in router.workers}
         self.block_length = eng.dcfg.block_length
         self.max_seq_len = min(w.engine.max_seq_len for w in router.workers)
         self.vocab = int(eng.model.cfg.vocab)
@@ -265,11 +272,10 @@ class ServeFrontend:
 
         try:
             # router hop: which replica took the request, and how long the
-            # pick + stage took (spans land on the event-loop thread lane)
-            with self.obs.trace.span("router.submit", cat="router",
-                                     args={"prompt_len": int(ids.size),
-                                           "trace": trace_id,
-                                           "class": slo_class}):
+            # pick + stage took
+            with self._spans.span("dllm.router.submit",
+                                  prompt_len=int(ids.size), trace=trace_id,
+                                  slo_class=slo_class):
                 worker = self.router.submit(req, deliver)
             self._submits.inc(replica=worker.name)
         except Overloaded as e:
@@ -283,7 +289,8 @@ class ServeFrontend:
 
         if stream:
             await self._stream_response(writer, events, int(ids.size), t0,
-                                        trace_id, th)
+                                        trace_id, th,
+                                        self._delivery[worker.name])
         else:
             await self._gathered_response(writer, events, int(ids.size),
                                           t0, trace_id, th)
@@ -291,8 +298,11 @@ class ServeFrontend:
     async def _stream_response(self, writer, events,
                                prompt_len: int, t0: float,
                                trace_id: Optional[str] = None,
-                               trace_headers: Optional[dict] = None
-                               ) -> None:
+                               trace_headers: Optional[dict] = None,
+                               delivery=None) -> None:
+        """SSE answer: one ``block_committed`` event per commit (each a
+        ``dllm.sse_write`` span, its delay since the engine made it
+        observed on ``delivery``), then ``done``."""
         self._count("/v1/completions", 200)
         writer.write(protocol.sse_headers(trace_headers))
         await writer.drain()
@@ -315,21 +325,25 @@ class ServeFrontend:
                 # buffered write, flushed by the transport: per-event
                 # drain() would wake the event loop per tick per slot and
                 # starve the worker threads of the GIL under load
-                p = protocol.commit_payload(ev)
-                if trace_id is not None:
-                    # server-layer stamp (not commit_payload): the event
-                    # log's block_commit records carry the identical
-                    # payload fields, and "trace" is this stream's join
-                    # key, not part of the commit delta
-                    p["trace"] = trace_id
-                writer.write(protocol.sse_event("block_committed", p))
+                with self._spans.span("dllm.sse_write", uid=ev.uid):
+                    p = protocol.commit_payload(ev)
+                    if trace_id is not None:
+                        # server-layer stamp (not commit_payload): the
+                        # event log's block_commit records carry the
+                        # identical payload fields, and "trace" is this
+                        # stream's join key, not part of the commit delta
+                        p["trace"] = trace_id
+                    writer.write(protocol.sse_event("block_committed", p))
+                    if delivery is not None:
+                        delivery.observe(time.perf_counter() - ev.created)
             if ev.done:
-                writer.write(protocol.sse_event("done",
-                             protocol.completion_payload(
-                                 ev.uid, self.model_name, prompt_len,
-                                 ev.final_tokens, ticks, ttft,
-                                 time.perf_counter() - t0,
-                                 trace_id=trace_id)))
+                with self._spans.span("dllm.sse_write", uid=ev.uid):
+                    writer.write(protocol.sse_event("done",
+                                 protocol.completion_payload(
+                                     ev.uid, self.model_name, prompt_len,
+                                     ev.final_tokens, ticks, ttft,
+                                     time.perf_counter() - t0,
+                                     trace_id=trace_id)))
                 break
         writer.write(protocol.SSE_DONE)
         await writer.drain()

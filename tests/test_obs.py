@@ -535,8 +535,8 @@ def test_engine_clock_audit_durations_nonnegative(engine_setup):
     for rec in eng.metrics.requests.values():
         assert rec.completed is None or rec.completed >= rec.arrival
         assert rec.admitted is None or rec.admitted >= rec.arrival
-    # tick trace spans are back-dated from measured stage boundaries and
-    # must still come out monotone per thread
+    # tick trace spans are emitted after their stage spans and must
+    # still come out monotone per thread
     ts = [e["ts"] for e in obs.trace.events()
           if e["ph"] == "X" and e["name"] == "tick"]
     assert ts == sorted(ts)
