@@ -107,16 +107,6 @@ EVENT_EMIT_PATHS: Dict[str, Tuple[str, ...]] = {
 # (d=4096, V=126464, d_head=128) at an 8-slot x L=32 engine batch.
 # ---------------------------------------------------------------------------
 
-# the ~4 MiB weight-slab cap applied by kernels/ops.fused_head_sampling so
-# the double-buffered slab fits a ~16 MiB/core VMEM budget at prod d
-W_SLAB_CAP_BYTES = 4 * 1024 * 1024
-
-
-def head_chunk_cap(d: int, itemsize: int) -> int:
-    """Vocab-chunk cap the fused-head wrapper applies (kernels/ops.py)."""
-    return max(128, W_SLAB_CAP_BYTES // (d * itemsize))
-
-
 @dataclasses.dataclass(frozen=True)
 class KernelSpec:
     name: str                       # public kernel entry
@@ -139,22 +129,29 @@ def kernel_specs(d: int = 4096, v: int = 126464, d_head: int = 128,
     """Per-kernel VMEM accounting at the given scale (defaults: LLaDA-8B
     production serving).  Dtypes: bf16 staging (2 B), fp32 scratch/accum
     (4 B), int32 indices (4 B) — matching the kernels' BlockSpecs."""
+    from repro.core.diffusion import DiffusionConfig
+    from repro.kernels import fused_head_sampling as fused_head_lib
+    from repro.kernels import ops
+
     bf16, f32, i32 = 2, 4, 4
     rows = batch * block_len                       # flattened (B*L, d)
     tile_r = 8
 
-    # fused_head_sampling: grid (Rp/tile_r, n_chunks); the wrapper caps the
-    # (d, chunk) slab at W_SLAB_CAP_BYTES before padding V
-    chunk = min(512, head_chunk_cap(d, bf16), v)
+    # fused_head_sampling: grid (Rp/head_r, n_chunks), the tiles the wrapper
+    # picks for the engine's vocab chunk; (head_r, 1) state blocks pad to
+    # 128 lanes
+    head_r, chunk = ops.head_tiles(rows, d, DiffusionConfig.head_chunk,
+                                   bf16)
     fused_head = KernelSpec(
         "fused_head_sampling",
-        {"rows": rows, "d": d, "V": v, "tile_r": tile_r, "chunk_v": chunk},
+        {"rows": rows, "d": d, "V": v, "tile_r": head_r, "chunk_v": chunk},
         {
-            "hidden_tile": tile_r * d * bf16,
+            "hidden_tile": head_r * d * bf16,
             "w_slab": d * chunk * bf16,
-            "out_conf": tile_r * f32,
-            "out_token": tile_r * i32,
-            "scratch": 5 * tile_r * f32,           # m/s/best/idx/carry rows
+            "logit_temps": fused_head_lib.LOGIT_TEMPS * head_r * chunk * f32,
+            "out_conf": head_r * 128 * f32,
+            "out_token": head_r * 128 * i32,
+            "scratch": 5 * head_r * 128 * f32,     # m/s/best/idx/carry rows
         },
         ("hidden_tile", "w_slab", "out_conf", "out_token"))
 

@@ -15,12 +15,24 @@ to 70% of inference latency.  This kernel streams the head GEMM instead:
 
       m' = max(m, m_c);  s' = s * e^(m - m') + sum_j e^(z_j - m')
 
-  so the logits live only in VMEM.  HBM traffic: R*d + d*V instead of R*V
-  (+ the R*V writeback the unfused head pays).  Mask-token suppression is a
+  so the logits live only in VMEM.  HBM traffic: R*d + (R / TILE_R)*d*V
+  instead of R*V (+ the R*V writeback the unfused head pays): the whole
+  head is streamed once per row tile.  Mask-token suppression is a
   comparator skip on the global column id; temperature > 0 adds a Gumbel
   perturbation drawn from the shared counter-based stream
-  (core/sampling.counter_gumbel) so the pure-jnp oracle
-  (core/sampling.fused_head_stable_max) reproduces the draw bit-for-bit.
+  (core/sampling.counter_gumbel), keyed by global row and column, so the
+  pure-jnp oracle (core/sampling.fused_head_stable_max) reproduces the
+  draw bit-for-bit whatever the tiles.
+
+Tiles.  Each weight byte feeds TILE_R multiply-adds, so TILE_R sets the
+kernel's arithmetic intensity: at 8 rows the MXU idles behind HBM (a v5e's
+ridge is ~240 FLOP/B).  ``kernels/ops.head_tiles`` picks TILE_R from the
+call's rows (up to 512, in the fewest equal tiles) and cuts CHUNK_V to
+bound the logit tile and ``vmem_bytes``; the kernel raises Mosaic's scoped
+VMEM limit to ``VMEM_LIMIT_BYTES`` to hold them.  The engine's tick at
+LLaDA-8B widths (16 slots x 32 = 512 rows) is one row tile, one pass of
+the head, in (512, 256) logit tiles; Qwen2-0.5B's (64 x 32 = 2048 rows,
+d 896) is four passes of a head a fifth the size.
 
 Outputs: confidence (R,) f32 and sampled token (R,) i32 — the L-sized
 FP/Int "domains" of the paper, written once at the final vocab chunk.
@@ -42,6 +54,25 @@ NEG = -1e30  # python float: pallas kernels cannot capture array constants
 
 SUPPORTED_FMTS = ("none", "bf16", "mxfp8_e4m3")
 _MX_BLOCK = mx.MX_BLOCK
+
+# Scoped VMEM the kernel may use: past Mosaic's 16 MiB default on a v5e,
+# well inside its 128 MiB of physical VMEM.
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+# (TILE_R, CHUNK_V) f32 temporaries of the body counted live at once: for a
+# v5e, Mosaic took ~3 beside the hidden tile and weight slab, with MXFP8
+# and temperature.  The (TILE_R, 1) state blocks pad to 128 lanes: 5
+# scratch rows and 2 double-buffered outputs.
+LOGIT_TEMPS = 4
+_STATE_ROWS = 5 + 2 * 2
+
+
+def vmem_bytes(tile_r: int, chunk_v: int, d: int, itemsize: int) -> int:
+    """VMEM one grid step holds: the double-buffered (TILE_R, d) hidden
+    tile and (d, CHUNK_V) weight slab, the logit-tile temporaries and the
+    per-row state."""
+    return (2 * (tile_r * d + d * chunk_v) * itemsize
+            + LOGIT_TEMPS * tile_r * chunk_v * 4
+            + _STATE_ROWS * tile_r * 128 * 4)
 
 
 def _block_amax(a: jax.Array, block: int) -> jax.Array:
@@ -149,15 +180,17 @@ def _kernel(seed_ref, h_ref, w_ref, conf_ref, idx_ref,
     "tile_r", "chunk_v", "fmt", "logit_scale", "temperature", "suppress_id",
     "interpret"))
 def fused_head_sampling(hidden: jax.Array, w_head: jax.Array,
-                        seed: jax.Array, *, tile_r: int = 8,
-                        chunk_v: int = 512, fmt: str = "none",
-                        logit_scale: float = 1.0, temperature: float = 0.0,
+                        seed: jax.Array, *, tile_r: int, chunk_v: int,
+                        fmt: str = "none", logit_scale: float = 1.0,
+                        temperature: float = 0.0,
                         suppress_id: Optional[int] = None,
                         interpret: bool = False
                         ) -> Tuple[jax.Array, jax.Array]:
     """hidden (R, d), w_head (d, V), seed uint32 scalar ->
     (conf (R,) f32, token (R,) i32).  Pads R and V (zero weight columns
-    produce exact-zero logits, masked to -inf before the reductions)."""
+    produce exact-zero logits, masked to -inf before the reductions).
+    ``kernels/ops.fused_head_sampling`` chooses the tiles for its calls
+    (``ops.head_tiles``)."""
     if fmt not in SUPPORTED_FMTS:
         raise ValueError(f"fmt {fmt!r} not in {SUPPORTED_FMTS}")
     R, d = hidden.shape
@@ -195,6 +228,8 @@ def fused_head_sampling(hidden: jax.Array, w_head: jax.Array,
                         pltpu.VMEM((tile_r, 1), jnp.float32),
                         pltpu.VMEM((tile_r, 1), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         # the profiler trace finds the head by this name
         name="fused_head_sampling",
     )(seed.reshape(1, 1).astype(jnp.uint32), hidden, w_head)

@@ -20,8 +20,39 @@ from repro.kernels import stablemax_sampling as _ss
 from repro.kernels import topk_mask as _tk
 
 
+# Rows per fused-head tile at most: each weight byte then feeds 512
+# multiply-adds, past a v5e's ridge of ~240 FLOP/B, so the MXU and not HBM
+# sets the pace.
+HEAD_ROW_TILE = 512
+# Logit-tile elements per grid step at most.  Mosaic unrolls the kernel
+# body over the tile, and its compile time grows faster than the tile: for
+# a v5e, (512 x 4096) x (4096 x 512) tiles took ~4 s, 512 x 256 ~1.3 s.
+HEAD_TILE_ELEMS = 512 * 256
+# VMEM the fused head's tiles may take (_fh.vmem_bytes), inside the
+# kernel's scoped limit _fh.VMEM_LIMIT_BYTES with room for Mosaic's own.
+HEAD_VMEM_BUDGET = 32 * 1024 * 1024
+
+
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def head_tiles(R: int, d: int, chunk_v: int,
+               itemsize: int) -> Tuple[int, int]:
+    """(tile_r, chunk_v) of the fused head for R rows of width d.  R splits
+    into the fewest equal row tiles of at most ``HEAD_ROW_TILE`` rows, each
+    a multiple of 8 (so under 8 padded rows a tile); the head is streamed
+    once per tile.  The vocab chunk is the caller's, cut where the tile
+    would pass ``HEAD_TILE_ELEMS`` or ``HEAD_VMEM_BUDGET``, and rounded
+    down to the 128 lanes a Mosaic block needs (a chunk under 128 stays:
+    only the interpreter runs it)."""
+    n = -(-R // HEAD_ROW_TILE)
+    tile_r = -(-R // (8 * n)) * 8
+    fixed = _fh.vmem_bytes(tile_r, 0, d, itemsize)
+    per_col = _fh.vmem_bytes(tile_r, 1, d, itemsize) - fixed
+    chunk = min(chunk_v, HEAD_TILE_ELEMS // tile_r,
+                (HEAD_VMEM_BUDGET - fixed) // per_col)
+    return tile_r, max(chunk // 128 * 128, min(chunk_v, 128))
 
 
 def fused_head_sampling(hidden: jax.Array, w_head: jax.Array, *,
@@ -29,7 +60,7 @@ def fused_head_sampling(hidden: jax.Array, w_head: jax.Array, *,
                         suppress_id: Optional[int] = None,
                         temperature: float = 0.0,
                         seed: Optional[jax.Array] = None,
-                        tile_r: int = 8, chunk_v: int = 512, quant=None,
+                        chunk_v: int = 512, quant=None,
                         interpret: Optional[bool] = None
                         ) -> Tuple[jax.Array, jax.Array]:
     """hidden (..., d) @ w_head (d, V) -> (conf (...), token (...)) without
@@ -48,11 +79,10 @@ def fused_head_sampling(hidden: jax.Array, w_head: jax.Array, *,
                 "temperature > 0 requires a seed: without one every call "
                 "would draw the identical counter-Gumbel noise stream")
         seed = jnp.uint32(0)
-    # cap the (d, CHUNK_V) weight slab at ~4 MB so the double-buffered
-    # block fits the ~16 MB/core VMEM budget at production d (the oracle's
-    # lax.scan has no such limit, so callers may pass much larger chunks)
-    cap = max(128, (4 * 1024 * 1024) // (d * flat.dtype.itemsize))
-    chunk_v = min(chunk_v, cap)
+    # the oracle's lax.scan has no VMEM limit, so callers may pass much
+    # larger chunks than the kernel's tiles hold
+    tile_r, chunk_v = head_tiles(flat.shape[0], d, chunk_v,
+                                 flat.dtype.itemsize)
     conf, idx = _fh.fused_head_sampling(
         flat, w_head, seed, tile_r=tile_r, chunk_v=chunk_v, fmt=fmt,
         logit_scale=logit_scale, temperature=temperature,

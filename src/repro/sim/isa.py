@@ -44,10 +44,10 @@ def is_mx(fmt: str) -> bool:
     return fmt.startswith("mx")
 
 
-# Row-tile of the fused-head Pallas kernel (kernels/fused_head_sampling.py
-# default tile_r): the per-grid-step logit tile staged in VMEM is
-# (TILE_R, chunk_v).  Kept here (not imported from the kernel) to avoid an
-# import cycle kernels -> sampling -> trace -> isa.
+# Row tile of the paper's NPU sampling model: the logit tile its fused head
+# stages in SRAM per vocab chunk is (TILE_R, chunk_v).  It models the NPU,
+# not the TPU kernel, which picks its own tiles per call
+# (kernels/ops.head_tiles).
 TILE_R = 8
 
 

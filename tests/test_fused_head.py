@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import base
 from repro.core import diffusion, sampling
+from repro.kernels import fused_head_sampling as fh
 from repro.kernels import ops
 from repro.models.layers import QuantPolicy
 from repro.models.registry import build_model
@@ -122,7 +123,30 @@ def test_kernel_matches_oracle(fmt, suppress):
         assert not bool(jnp.any(i_kn == suppress))
 
 
-@pytest.mark.parametrize("R,d,V", [(1, 32, 64), (8, 64, 512), (32, 48, 1000)])
+@pytest.mark.parametrize("d", [64, 896, 4096])
+@pytest.mark.parametrize("R", [8, 32, 40, 300, 512, 2048])
+def test_head_tiles(R, d):
+    """The fewest equal row tiles, each padded by under 8 rows; a vocab
+    chunk never past the caller's, cut to 128-lane multiples, and tiles
+    inside the logit-tile and VMEM budgets."""
+    for caller in (100, 2336, 4096):
+        tile_r, chunk = ops.head_tiles(R, d, caller, 2)
+        n = -(-R // tile_r)
+        assert tile_r % 8 == 0 and tile_r <= ops.HEAD_ROW_TILE
+        assert n == -(-R // ops.HEAD_ROW_TILE)
+        assert n * tile_r - R < 8 * n
+        assert chunk <= caller
+        if chunk < caller:
+            assert chunk % 128 == 0
+        assert tile_r * chunk <= ops.HEAD_TILE_ELEMS
+        assert fh.vmem_bytes(tile_r, chunk, d, 2) <= ops.HEAD_VMEM_BUDGET
+    # the engine's ticks: one pass of LLaDA-8B's head, four of Qwen2's
+    assert ops.head_tiles(512, 4096, 4096, 2) == (512, 256)
+    assert ops.head_tiles(2048, 896, 2048, 2) == (512, 256)
+
+
+@pytest.mark.parametrize("R,d,V", [(1, 32, 64), (8, 64, 512), (32, 48, 1000),
+                                   (40, 64, 1000), (300, 48, 1000)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kernel_shape_dtype_sweep(R, d, V, dtype):
     h, w = _hw(R + V, R=R, d=d, V=V, dtype=dtype)
@@ -160,19 +184,28 @@ def test_odd_chunk_width_rounds_to_mx_blocks():
 
 
 @pytest.mark.parametrize("fmt", FMTS)
-def test_kernel_temperature_matches_oracle(fmt):
+@pytest.mark.parametrize("R", [13, 600])
+def test_kernel_temperature_matches_oracle(fmt, R):
     """Gumbel sampling: kernel and oracle share the counter-based noise
-    stream, so the sampled tokens agree exactly given the same seed."""
-    h, w = _hw(20)
+    stream, so the sampled tokens agree exactly given the same seed.  The
+    draw is keyed by global row, so 8-row tiles draw what the chosen tiles
+    (two of 304 rows at R=600) draw."""
+    h, w = _hw(20, R=R)
     rng = jax.random.PRNGKey(9)
+    seed = sampling.gumbel_seed(rng)
     c_or, i_or = sampling.fused_head_stable_max(
         h, w, fmt, rng=rng, temperature=0.8, suppress_id=5, chunk_v=64)
     c_kn, i_kn = ops.fused_head_sampling(
-        h, w, fmt=fmt, temperature=0.8, suppress_id=5,
-        seed=sampling.gumbel_seed(rng), chunk_v=64)
+        h, w, fmt=fmt, temperature=0.8, suppress_id=5, seed=seed,
+        chunk_v=64)
     np.testing.assert_array_equal(i_or, i_kn)
     np.testing.assert_allclose(c_or, c_kn, rtol=1e-6)
     assert not bool(jnp.any(i_kn == 5))
+    c_8, i_8 = fh.fused_head_sampling(
+        h, w, seed, tile_r=8, chunk_v=64, fmt=fmt, temperature=0.8,
+        suppress_id=5, interpret=True)
+    np.testing.assert_array_equal(i_8, i_kn)
+    np.testing.assert_allclose(c_8, c_kn, rtol=1e-6)
     # conf is the softmax prob of the *sampled* token (LLaDA convention),
     # taken over the fmt-quantized logits
     from repro.core import mx

@@ -26,11 +26,14 @@ from repro.core import baos as baos_lib
 from repro.core import diffusion
 from repro.core import sampling as sampling_lib
 from repro.kernels import fused_head_sampling as fh
+from repro.kernels import ops
 from repro.kernels import topk_mask as tk
 from repro.models.registry import build_model
 
 D, VOCAB, ROWS = 4096, 126464, 512      # LLaDA-8B head; 16 slots x block 32
 MASK_ID = 126336
+# the engine's vocab chunk (DiffusionConfig.head_chunk)
+HEAD_CHUNK = diffusion.DiffusionConfig.head_chunk
 
 
 @pytest.fixture(scope="module")
@@ -68,26 +71,54 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_head(one_chip, fmt, temperature):
+def _compile_head(one_chip, fmt, temperature, rows=ROWS, d=D, vocab=VOCAB,
+                  chunk_v=HEAD_CHUNK):
+    """The head as the engine calls it (ops.fused_head_sampling picks the
+    tiles), lowered for the chip; also returns the kernel's grid."""
     def head(h, w, seed):
-        return fh.fused_head_sampling(h, w, seed, fmt=fmt,
-                                      temperature=temperature,
-                                      suppress_id=MASK_ID)
-    return jax.jit(head).lower(
-        _spec((ROWS, D), jnp.bfloat16, one_chip),
-        _spec((D, VOCAB), jnp.bfloat16, one_chip),
-        _spec((), jnp.uint32, one_chip)).compile()
+        return ops.fused_head_sampling(h, w, fmt=fmt, temperature=temperature,
+                                       seed=seed, suppress_id=MASK_ID,
+                                       chunk_v=chunk_v, interpret=False)
+    args = (_spec((rows, d), jnp.bfloat16, one_chip),
+            _spec((d, vocab), jnp.bfloat16, one_chip),
+            _spec((), jnp.uint32, one_chip))
+    jaxpr = jax.make_jaxpr(head)(*args)
+    grids = [e.params["grid_mapping"].grid
+             for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    return jax.jit(head).lower(*args).compile(), grids
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 @pytest.mark.parametrize("fmt", fh.SUPPORTED_FMTS)
 def test_fused_head_compiles_at_llada_width(one_chip, fmt):
-    compiled = _compile_head(one_chip, fmt, 0.0)
+    """At the engine's tick, 512 rows: at most two row tiles, so the
+    (4096, 126464) head is streamed at most twice a call."""
+    compiled, grids = _compile_head(one_chip, fmt, 0.0)
     assert "tpu_custom_call" in compiled.as_text()
+    assert len(grids) == 1 and grids[0][0] <= 2
 
 
 def test_fused_head_compiles_with_temperature(one_chip):
     """The in-kernel counter-Gumbel draw (uint32 hash -> f32) lowers."""
-    compiled = _compile_head(one_chip, "mxfp8_e4m3", 0.7)
+    compiled, _ = _compile_head(one_chip, "mxfp8_e4m3", 0.7)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+@pytest.mark.parametrize("fmt", fh.SUPPORTED_FMTS)
+@pytest.mark.parametrize("chunk_v", [2048, 4096])
+def test_fused_head_compiles_at_qwen2_width(one_chip, chunk_v, fmt,
+                                            temperature):
+    """Qwen2-0.5B's tick: 64 slots x 32 rows over its (896, 151936) head,
+    at the benchmark's chunk and at the engine's."""
+    compiled, _ = _compile_head(one_chip, fmt, temperature, rows=2048,
+                                d=896, vocab=151936, chunk_v=chunk_v)
     assert "tpu_custom_call" in compiled.as_text()
 
 
