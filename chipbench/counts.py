@@ -6,6 +6,11 @@ request's L-position active block attending to its real context, and the
 LM head over those L rows; per block, one forward over the request's real
 (unpadded) positions.  Recomputed or padded work does not count, so a
 program that removes it raises the share of the peak and never passes it.
+
+This count is of a dense transformer that attends over the whole real
+length.  A configuration's reference module (``ref``) that defines
+``tick_flops``, ``head_flops`` or ``head_bytes`` counts its own
+architecture's least work in their place, with the same arguments.
 """
 from __future__ import annotations
 
@@ -24,20 +29,26 @@ def attn_flops(m: dict, queries: int, keys: int) -> int:
     return 4 * m["n_layers"] * m["n_heads"] * m["d_head"] * queries * keys
 
 
-def head_flops(m: dict, rows: int) -> int:
+def head_flops(m: dict, rows: int, ref=None) -> int:
+    if hasattr(ref, "head_flops"):
+        return ref.head_flops(m, rows)
     return 2 * rows * m["d_model"] * m["vocab"]
 
 
-def head_bytes(m: dict, rows: int, weight_itemsize: int) -> int:
+def head_bytes(m: dict, rows: int, weight_itemsize: int, ref=None) -> int:
     """The (d, V) head read once, the hidden rows in, (conf, token) out."""
+    if hasattr(ref, "head_bytes"):
+        return ref.head_bytes(m, rows, weight_itemsize)
     act = 4 if m["dtype"] == "float32" else 2
     return (m["d_model"] * m["vocab"] * weight_itemsize
             + rows * m["d_model"] * act + rows * 8)
 
 
-def tick_flops(m: dict, block: int, active) -> int:
+def tick_flops(m: dict, block: int, active, ref=None) -> int:
     """Required operations of one tick.  ``active``: (real length, first
     tick of a block) of each active request."""
+    if hasattr(ref, "tick_flops"):
+        return ref.tick_flops(m, block, active)
     total = 0
     for length, block_start in active:
         total += block * dense_flops_per_token(m)

@@ -6,7 +6,7 @@ import counts
 
 
 def read(run):
-    m, pk = run.model, run.peaks
+    m, pk, ref = run.model, run.peaks, run.reference
     block = run.config["serving"]["block_length"]
     per_tick = counts.traced_ticks(run)
     itemsize = 4 if m["dtype"] == "float32" else 2
@@ -14,9 +14,9 @@ def read(run):
     for d in run.trace["devices"].values():
         if not d["head_calls"] or not per_tick:
             continue
-        least = [max(counts.head_flops(m, len(a) * block)
+        least = [max(counts.head_flops(m, len(a) * block, ref)
                      / pk["bf16_flops_s"],
-                     counts.head_bytes(m, len(a) * block, itemsize)
+                     counts.head_bytes(m, len(a) * block, itemsize, ref)
                      / pk["hbm_bytes_s"]) for a in per_tick]
         share.append(d["head_calls"] * sum(least) / len(least) / d["head_s"])
     return 100.0 * sum(share) / len(share) if share else None

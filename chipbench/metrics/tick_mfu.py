@@ -1,6 +1,7 @@
 """Required operations of the ticks in the traced window over the device
 time they spanned, over the chip's bf16 peak (percent).  "Required" is the
-least algorithm's work (chipbench/counts.py).  Layer: tick on device."""
+least algorithm's work (chipbench/counts.py, or the configuration's
+reference module where it counts its own).  Layer: tick on device."""
 import counts
 
 
@@ -10,7 +11,8 @@ def read(run):
     per_tick = counts.traced_ticks(run)
     if not per_tick:
         return None
-    flops = sum(counts.tick_flops(run.model, block, a) for a in per_tick)
+    flops = sum(counts.tick_flops(run.model, block, a, run.reference)
+                for a in per_tick)
     share = []
     for d in devs.values():
         if d["ticks"] > 1 and d["tick_span_s"] > 0:

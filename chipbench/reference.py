@@ -1,9 +1,50 @@
 """The served model's weights, its plain reference, and the control.
 
-The benchmark makes the weights itself (``init_weights``: one jitted call
-from the seed, on the device, in the served dtype) and hands them to the
-program in the program's parameter layout.  After the window it makes them
-again from the seed for the reference, which imports nothing of the program.
+A configuration names its reference module (``"reference": "<module>"`` in
+``chipbench/configs/<name>.json``, this module where the key is absent);
+the harness finds it as ``chipbench/<module>.py``.  A new architecture adds
+such a module and imports nothing of the program.  The contract:
+
+``layout(m) -> dict``
+    Shapes (tuples) of the parameter tree, in the program's layout, for the
+    configuration's ``model`` dict ``m``.
+``init_weights(m, init, seed) -> pytree``
+    Random weights of that layout from the seed, made on the device in one
+    jitted call in the served dtype (``init``: the configuration's
+    ``init``).  The harness hands them to the program, and refuses a
+    program whose parameter tree differs from them in structure, shape or
+    dtype; after the window it makes them again for ``check_rows``.
+``blocks(prompt_len, gen_len, L, T) -> [(start, end, ks), ...]``
+    The blocks a request's generation commits, in order: each block's
+    positions ``start <= p < end`` in the canvas of ``prompt_len + gen_len``
+    positions, and ``ks``, the number of positions it commits at each step
+    (``L``, ``T``: the configuration's block length and steps per block).
+    A commit event breaks the grid when its block or step is out of order,
+    it commits other than the step's count, or a position lies outside the
+    block or was not masked before.
+``canvases(prompt, gen, events, mask_id) -> (rows, final)``
+    ``rows``: (canvas, positions, tokens) of each commit event, the canvas
+    as the served path saw it before the commit; ``final``: the canvas
+    after the last.  ``events``: (positions, tokens) of each.
+``check_rows(params, m, rows, seq_len, block, control=False) -> dict``
+    ``rows``: (canvas, positions, tokens, block start, block end, k) per
+    commit; ``m`` adds ``mask_id``; ``block``: the configuration's block
+    length, which no block exceeds.  Returns numpy arrays: ``gap``, the
+    reference's logit gap of every committed token, and ``conf_gap``, the
+    confidence shortfall of every commit; with ``control`` also
+    ``control_gap`` and ``control_conf_gap``, the control's.
+
+Optional, where the least work differs from ``chipbench/counts.py``'s dense
+count: ``tick_flops(m, block, active)``, ``head_flops(m, rows)`` and
+``head_bytes(m, rows, weight_itemsize)``, with the arguments and meaning of
+the functions of that name there.  A module may take what it shares with
+this one from it: ``random_weights`` for its own layout, ``canvases``, and
+``check_rows`` with its own plain forward (``forward=``).
+
+This module is the default: a bidirectional dense dLLM.  The benchmark
+makes the weights itself (``init_weights``) and hands them to the program
+in the program's parameter layout.  After the window it makes them again
+from the seed for the reference, which imports nothing of the program.
 
 ``check_rows`` runs the plain forward of a bidirectional dLLM in float32 at
 ``highest`` matmul precision, with keys and values in the configuration's
@@ -57,14 +98,20 @@ def layout(m: dict) -> dict:
 
 
 def init_weights(m: dict, init: dict, seed: int):
-    """Random weights from the seed, made on the device in one jitted call.
+    """Random weights of ``layout(m)`` from the seed (``random_weights``)."""
+    return random_weights(layout(m), m, init, seed)
 
-    Matrices are N(0, 1/fan_in); norm weights 1 + N(0, norm_jitter); QKV
-    biases N(0, bias_std); the embedding N(0, embed_std); an untied head
+
+def random_weights(shapes: dict, m: dict, init: dict, seed: int):
+    """Random weights of the tree of ``shapes`` from the seed, made on the
+    device in one jitted call.
+
+    Matrices are N(0, 1/fan_in); norm weights (a leaf whose path holds
+    ``ln`` or ``norm``) 1 + N(0, norm_jitter); biases (a leaf named ``b*``)
+    N(0, bias_std); the embedding N(0, embed_std); an untied head
     N(0, logit_std^2 / d), so that logits spread by about ``logit_std``.
     A tied head is the embedding's transpose."""
     dt = jnp.dtype(m["dtype"])
-    shapes = layout(m)
     leaves, treedef = jax.tree_util.tree_flatten(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
     names = [jax.tree_util.keystr(p) for p, _ in
@@ -230,12 +277,13 @@ def _log_conf(z):
     return jnp.max(z, -1) - jax.nn.logsumexp(z, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("mk", "control"))
-def _gaps(params, tokens, valid, pos, tok, blk, mk, control):
+@functools.partial(jax.jit, static_argnames=("mk", "control", "forward"))
+def _gaps(params, tokens, valid, pos, tok, blk, mk, control, forward):
     """Per row: the served tokens' logit gaps at ``pos`` and the
     log-confidence of every position in ``blk`` (the active block), taken
     as the configuration states it, over logits read in MXFP8; with
-    ``control`` the control's token gaps and block log-confidences."""
+    ``control`` the control's token gaps and block log-confidences.
+    ``forward``: the final-norm hidden states, as ``hidden`` gives them."""
     m = dict(mk)
     head = params["lm_head"].astype(jnp.float32)
     mask_id = m["mask_id"]
@@ -249,7 +297,7 @@ def _gaps(params, tokens, valid, pos, tok, blk, mk, control):
         z = fmt(z) if fmt else z
         return z.at[..., mask_id].set(-jnp.inf)
 
-    h = hidden(params, tokens, valid, m)
+    h = forward(params, tokens, valid, m)
     z = logits(at(h, pos), False)
 
     def gap(t):
@@ -259,10 +307,18 @@ def _gaps(params, tokens, valid, pos, tok, blk, mk, control):
     out = {"gap": gap(tok),
            "conf": _log_conf(logits(at(h, blk), False, mxfp8))}
     if control:
-        hc = hidden(params, tokens, valid, m, True)
+        hc = forward(params, tokens, valid, m, True)
         out["control_gap"] = gap(jnp.argmax(logits(at(hc, pos), True), -1))
         out["control_conf"] = _log_conf(logits(at(hc, blk), True))
     return out
+
+
+def blocks(prompt_len: int, gen_len: int, L: int, T: int) -> list:
+    """Blocks of ``L`` positions from the end of the prompt; each commits
+    the block length spread evenly over ``T`` steps, the remainder first."""
+    ks = [L // T + (t < L % T) for t in range(T)]
+    return [(prompt_len + b * L, prompt_len + (b + 1) * L, ks)
+            for b in range(gen_len // L)]
 
 
 def canvases(prompt, gen, events, mask_id):
@@ -285,10 +341,14 @@ def conf_shortfall(conf, masked, picked, k: int) -> float:
 
 
 def check_rows(params, m: dict, rows, seq_len: int, block: int,
-               batch: int = 8, control: bool = False) -> dict:
-    """``rows``: (canvas, positions, tokens, block start, k) per commit.
-    Returns, as numpy arrays, the reference gap of every committed token
-    and the confidence shortfall of every commit (and the control's)."""
+               batch: int = 8, control: bool = False,
+               forward=hidden) -> dict:
+    """``rows``: (canvas, positions, tokens, block start, block end, k) per
+    commit.  Returns, as numpy arrays, the reference gap of every committed
+    token and the confidence shortfall of every commit (and the
+    control's).  ``block``: the configuration's block length, which no
+    block exceeds; ``forward``: the plain forward (a reference module of
+    another architecture passes its own)."""
     K = max(len(r[1]) for r in rows)
     mk = tuple(sorted({**m, "mask_id": m["mask_id"]}.items()))
     out = {"gap": [], "conf_gap": []}
@@ -302,7 +362,7 @@ def check_rows(params, m: dict, rows, seq_len: int, block: int,
         tok = np.zeros((batch, K), np.int32)
         keep = np.zeros((batch, K), bool)
         blk = np.zeros((batch, block), np.int32)
-        for j, (c, p, t, bs, _) in enumerate(chunk):
+        for j, (c, p, t, bs, _, _) in enumerate(chunk):
             tokens[j, :c.size] = c
             valid[j, :c.size] = True
             pos[j, :p.size], tok[j, :t.size] = p, t
@@ -310,14 +370,14 @@ def check_rows(params, m: dict, rows, seq_len: int, block: int,
             blk[j] = np.minimum(bs + np.arange(block), seq_len - 1)
         valid[len(chunk):, 0] = True           # filler rows: one valid key
         got = jax.device_get(_gaps(params, tokens, valid, pos, tok, blk, mk,
-                                   control))
+                                   control, forward))
         out["gap"].append(got["gap"][keep])
         if control:
             out["control_gap"].append(got["control_gap"][keep])
-        for j, (c, p, _, bs, k) in enumerate(chunk):
-            masked = c[blk[j]] == m["mask_id"]
-            masked &= bs + np.arange(block) < c.size
-            inside = (p >= bs) & (p < bs + block)
+        for j, (c, p, _, bs, be, k) in enumerate(chunk):
+            masked = c[np.minimum(blk[j], c.size - 1)] == m["mask_id"]
+            masked &= bs + np.arange(block) < be
+            inside = (p >= bs) & (p < be)
             if not masked.any() or not inside.any():
                 continue        # no such commit: counted as a mismatch
             picked = np.zeros(block, bool)

@@ -13,8 +13,9 @@ client process that imports no JAX.  After a warm-up period of the same
 traffic it measures for ``--seconds``; with ``--trace 1`` a profiler trace
 covers a few seconds in the middle of the window and the per-layer metrics
 are reported instead of the end-to-end ones.  Once the window has closed the
-served tokens of a sample of finished requests are compared with a plain
-reference (``chipbench/reference.py``).
+served tokens of a sample of finished requests are compared with the plain
+reference that the configuration names (``chipbench/reference.py`` unless
+its ``"reference"`` key names another module of ``chipbench/``).
 
 The last line of standard output is the JSON result.  A machine whose first
 JAX device is not a TPU, or that has fewer chips than the cell asks for,
@@ -29,6 +30,7 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -46,7 +48,6 @@ sys.path.insert(0, HERE)
 import numpy as np  # noqa: E402
 
 import client as client_lib  # noqa: E402
-import reference  # noqa: E402
 import trace_reduce  # noqa: E402
 
 CACHE_DIR = os.path.join(ROOT, ".xla_cache")
@@ -79,16 +80,33 @@ def cell_spec(name: str, bench_path: str = os.path.join(ROOT,
             mine(bench["end_to_end"]), mine(bench["per_layer"]))
 
 
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str):
     """``metrics/<metric>.py``; a metric split by the end-to-end metric it
     moves (``<quantity>.<suffix>``) falls back to the quantity's reader."""
     path = os.path.join(HERE, "metrics", metric + ".py")
     if not os.path.exists(path):
         path = os.path.join(HERE, "metrics", metric.split(".")[0] + ".py")
-    spec = importlib.util.spec_from_file_location(f"metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"metric_{metric}").read
+
+
+@functools.cache
+def _reference(name: str):
+    return _module(os.path.join(HERE, name + ".py"), name)
+
+
+def reference_of(config: dict):
+    """The configuration's plain reference: ``<module>.py`` beside this
+    file for its ``"reference": "<module>"`` key, ``reference.py`` without
+    one (the contract is at the top of ``reference.py``).  Loaded once a
+    process."""
+    return _reference(config.get("reference", "reference"))
 
 
 class CompileClock:
@@ -200,21 +218,21 @@ def build(config: dict, seed: int):
     from repro.models.transformer import ModelConfig
 
     m = config["model"]
+    ref = reference_of(config)
     fields = {f.name for f in dataclasses.fields(ModelConfig)}
     cfg = dataclasses.replace(base.get_config(config["arch"]),
                               **{k: v for k, v in m.items() if k in fields})
     model = build_model(cfg)
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    mine = jax.eval_shape(lambda: reference.init_weights(
-        m, config["init"], seed))
+    mine = jax.eval_shape(lambda: ref.init_weights(m, config["init"], seed))
     if (jax.tree_util.tree_structure(want)
             != jax.tree_util.tree_structure(mine)
             or any(a.shape != b.shape or a.dtype != b.dtype for a, b in zip(
                 jax.tree_util.tree_leaves(want),
                 jax.tree_util.tree_leaves(mine)))):
         raise RuntimeError("the program's parameter layout differs from "
-                           "chipbench/reference.layout")
-    params = reference.init_weights(m, config["init"], seed)
+                           f"{ref.__name__}.layout ({ref.__file__})")
+    params = ref.init_weights(m, config["init"], seed)
     jax.block_until_ready(params)
     return model, params
 
@@ -394,43 +412,49 @@ def lateness(run: dict) -> str:
 
 # -- correctness ------------------------------------------------------------
 
-def schedule(config: dict):
-    """Tokens the block's schedule commits at each step: the block length
-    spread evenly over the steps, the remainder first."""
-    s = config["serving"]
-    L, T = s["block_length"], s["steps_per_block"]
-    return [L // T + (t < L % T) for t in range(T)]
-
-
 def commits(r: dict, config: dict):
     """The request's commit events with the block each belongs to, and how
-    many of them break the block schedule: a block or step out of order, a
-    commit of other than the step's k positions, or a position outside the
-    active block or not masked before."""
+    many of them break the reference's block grid (``blocks``): a block or
+    step out of order, a commit of other than the step's k positions, or a
+    position outside the active block or not masked before.  An event past
+    the grid's last block breaks it."""
+    ref = reference_of(config)
     mask_id = config["model"]["mask_token_id"]
-    L = config["serving"]["block_length"]
-    ks = schedule(config)
-    evs, final = reference.canvases(
+    s = config["serving"]
+    grid = ref.blocks(len(r["prompt"]), r["gen"], s["block_length"],
+                      s["steps_per_block"])
+    end = len(r["prompt"]) + r["gen"]
+    evs, final = ref.canvases(
         r["prompt"], r["gen"], [(e[4], e[5]) for e in r["events"]], mask_id)
-    rows, bad, b, t, left = [], 0, 0, 0, L
+
+    def block(b):
+        return grid[b] if b < len(grid) else (end, end, [])
+
+    rows, bad, b, t = [], 0, 0, 0
+    bs, be, ks = block(0)
+    left = be - bs
     for e, (canvas, pos, tok) in zip(r["events"], evs):
-        bs = len(r["prompt"]) + b * L
         k = ks[t] if t < len(ks) else left
         bad += int(e[2] != b or e[3] != t or len(pos) != k
-                   or not np.all((pos >= bs) & (pos < bs + L))
+                   or not np.all((pos >= bs) & (pos < be))
                    or not np.all(canvas[pos] == mask_id))
-        rows.append((canvas, pos, tok, bs, k))
+        rows.append((canvas, pos, tok, bs, be, k))
         left -= len(pos)
-        b, t, left = (b + 1, 0, L) if left <= 0 else (b, t + 1, left)
+        if left > 0:
+            t += 1
+            continue
+        b, t = b + 1, 0
+        bs, be, ks = block(b)
+        left = be - bs
     return rows, bad, final
 
 
 def served_rows(run: dict, config: dict, seed: int, closed: bool):
     """A sample drawn from the seed of the requests finished in the window,
     the longest first, until ``check.tokens`` served tokens: each commit's
-    canvas, positions, tokens, block start and scheduled k.  Also the
-    number of requests whose stream disagrees with its own final answer,
-    and the number of commits that break the block schedule."""
+    canvas, positions, tokens, block start and end, and scheduled k.  Also
+    the number of requests whose stream disagrees with its own final
+    answer, and the number of commits that break the block grid."""
     mask_id = config["model"]["mask_token_id"]
     ok = [r for r in run["records"] if r.get("status") == "ok"
           and (in_window(run, r["done"]) if closed
@@ -455,7 +479,7 @@ def served_rows(run: dict, config: dict, seed: int, closed: bool):
             broken += 1
         bad += off
         rows += evs
-        n_tok += sum(len(p) for _, p, _, _, _ in evs)
+        n_tok += sum(len(row[1]) for row in evs)
         n_req += 1
     return rows, n_req, broken, bad
 
@@ -475,12 +499,11 @@ def judge(run, config, seed, closed, control=False):
              "commit_mismatch": {"value": bad, "limit": 0}}
     if not rows:
         return {"served_requests": {"value": 0, "limit": -1}, **exact}, None
+    ref = reference_of(config)
     m = {**config["model"], "mask_id": config["model"]["mask_token_id"]}
-    params = reference.init_weights(config["model"], config["init"], seed)
-    out = reference.check_rows(params, m, rows,
-                               config["serving"]["max_seq_len"],
-                               config["serving"]["block_length"],
-                               control=control)
+    params = ref.init_weights(config["model"], config["init"], seed)
+    out = ref.check_rows(params, m, rows, config["serving"]["max_seq_len"],
+                         config["serving"]["block_length"], control=control)
     got = check_numbers(out["gap"], out["conf_gap"])
     check = dict(exact)
     for k, lim in config["check"]["limits"].items():
@@ -610,7 +633,8 @@ def layer_metrics(run, config, traffic, per_layer, seconds, kind):
     ctx = types.SimpleNamespace(
         records=run["records"], window=run["window"], scrapes=run["scrapes"],
         trace=red, trace_ticks=t["ticks"], config=config, traffic=traffic,
-        model=config["model"], peaks=peaks, seconds=seconds)
+        model=config["model"], reference=reference_of(config), peaks=peaks,
+        seconds=seconds)
     out = {}
     for m in per_layer:
         v = reader(m["name"])(ctx)

@@ -6,9 +6,11 @@ the event's short string stats (the HLO op and module names among them).
 ``reduce`` takes that list and the traced window and returns, per device
 plane: the union of the intervals in which an operation ran (busy), the
 operations that took most time, the idle gaps with the host event that
-overlapped each most, the head kernel's calls, and the tick programs: the
-module runs that hold a head call (the jitted tick carries no name of its
-own in the trace today).
+overlapped each most, every kernel's calls and device time (``kernels``,
+by the stem of the op's name, so that a reader of a new kernel's roofline
+finds its time by name), the head kernel's calls, and the tick programs:
+the module runs that hold a head call (the jitted tick carries no name of
+its own in the trace today).
 The list is what a test keeps as its recorded trace.
 """
 from __future__ import annotations
@@ -69,10 +71,18 @@ def short(name: str) -> str:
     return name.split(" = ", 1)[0]
 
 
+def stem(name: str) -> str:
+    """An HLO op's name without its signature, ``%`` and instance number:
+    ``%fused_head_sampling.1 = ...`` -> ``fused_head_sampling``."""
+    return re.sub(r"\.\d+$", "", short(name).lstrip("%"))
+
+
 def reduce(events: list, window_ns: tuple, top: int = 10) -> dict:
     """Per device plane, within [w0, w1): busy and idle seconds, the top
     operations by summed time, the longest idle gaps with what the host was
-    doing, the head kernel's calls and the tick program's runs."""
+    doing, the head kernel's calls and the tick program's runs; and every
+    kernel's calls and summed device seconds by the stem of its name, over
+    every op of the trace, each whole, as the head's are counted."""
     w0, w1 = window_ns
     host = [(s, e, n) for p, ln, n, s, e, _ in events
             if p.startswith("/host") and e > s]
@@ -102,6 +112,10 @@ def reduce(events: list, window_ns: tuple, top: int = 10) -> dict:
                 if ov > best:
                     best, what = ov, n
             named.append([what, (g1 - g0) / 1e9])
+        kernels = {}
+        for ev in ops:
+            n, ns = kernels.get(stem(ev[2]), (0, 0))
+            kernels[stem(ev[2])] = (n + 1, ns + ev[4] - ev[3])
         head = [ev for ev in ops if _matches(ev, HEAD)]
         ticks = sorted((ev[3], ev[4]) for ev in events
                        if ev[0] == dev and ev[1] == MODULES_LINE
@@ -114,6 +128,8 @@ def reduce(events: list, window_ns: tuple, top: int = 10) -> dict:
             "top_ops": [[n, t / 1e9] for n, t in
                         sorted(by_op.items(), key=lambda kv: -kv[1])[:top]],
             "idle_gaps": named,
+            "kernels": {k: {"calls": n, "s": ns / 1e9}
+                        for k, (n, ns) in kernels.items()},
             "head_calls": len(head),
             "head_s": sum(e - s for _, _, _, s, e, _ in head) / 1e9,
             "ticks": len(ticks),

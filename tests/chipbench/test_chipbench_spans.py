@@ -102,11 +102,15 @@ def test_idle_at_the_edges_and_between_spans():
 
 
 def test_the_reduction_keeps_its_keys():
-    """trace_reduce.reduce gives what it gave before the spans existed:
-    the spans name the idle gaps by overlap, as any host event does."""
+    """trace_reduce.reduce gives what it gave before the spans existed,
+    and the kernel table: the spans name the idle gaps by overlap, as any
+    host event does, and are no kernels."""
     d = trace_reduce.reduce(_events(), (0, 100 * MS))["devices"][DEV]
     assert set(d) == {"busy_s", "idle_share", "top_ops", "idle_gaps",
-                      "head_calls", "head_s", "ticks", "tick_span_s"}
+                      "kernels", "head_calls", "head_s", "ticks",
+                      "tick_span_s"}
+    assert set(d["kernels"]) == {"while", "fusion", "fused_head_sampling",
+                                 "scatter", "copy"}
     assert d["head_calls"] == 1 and d["ticks"] == 1
     assert d["busy_s"] == pytest.approx(0.039)
 

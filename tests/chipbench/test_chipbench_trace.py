@@ -39,6 +39,9 @@ def test_busy_idle_gaps_head_and_ticks_by_hand():
     assert d["head_calls"] == 1 and d["head_s"] == pytest.approx(0.01)
     # a tick is a module run that holds a head call: only the second
     assert d["ticks"] == 1 and d["tick_span_s"] == pytest.approx(0.02)
+    assert d["kernels"] == {
+        "fusion": {"calls": 2, "s": pytest.approx(0.025)},
+        "fused_head_sampling": {"calls": 1, "s": pytest.approx(0.01)}}
 
 
 def test_recorded_chip_trace():
@@ -51,3 +54,45 @@ def test_recorded_chip_trace():
     for key, want in rec["expect"].items():
         # the expectations were reckoned on a 1 us grid
         assert d[key] == pytest.approx(want, abs=5e-5), key
+
+
+def _recorded():
+    with open(os.path.join(HERE, "data", "trace_llada.json")) as f:
+        rec = json.load(f)
+    d = trace_reduce.reduce(rec["events"], tuple(rec["window_ns"]))[
+        "devices"][rec["device"]]
+    return rec, d
+
+
+def test_recorded_head_reads_as_before():
+    """The head's calls and time in the recorded trace, as the reduction
+    read them before it kept a table of every kernel."""
+    _, d = _recorded()
+    assert d["head_calls"] == 1
+    assert d["head_s"] == 0.087627607
+
+
+def test_recorded_kernel_table():
+    """The kernel table holds every op of the device once, whole, by the
+    stem of its name; the head's entry is the head's calls and time."""
+    rec, d = _recorded()
+    ops = [ev for ev in rec["events"]
+           if ev[0] == rec["device"] and ev[1] == "XLA Ops"]
+    assert sum(k["calls"] for k in d["kernels"].values()) == len(ops)
+    assert sum(k["s"] for k in d["kernels"].values()) == pytest.approx(
+        sum(ev[4] - ev[3] for ev in ops) / 1e9, rel=1e-12)
+    assert d["kernels"]["fused_head_sampling"] == {
+        "calls": d["head_calls"], "s": d["head_s"]}
+    assert all("%" not in k and not k[-1].isdigit() for k in d["kernels"])
+
+
+@pytest.mark.parametrize("name, want", [
+    ("%fused_head_sampling.1 = (f32[8,1]) custom-call(%a)",
+     "fused_head_sampling"),
+    ("%fusion.212", "fusion"),
+    ("%slice-start", "slice-start"),
+    ("%copy-start.13", "copy-start"),
+    ("%topk_mask", "topk_mask"),
+])
+def test_kernel_stem(name, want):
+    assert trace_reduce.stem(name) == want
